@@ -2,9 +2,10 @@
 count on the SPMD serving path, parity asserted at every point.
 
 Each row spawns repro.launch.shard_serve in a SUBPROCESS with
---xla_force_host_platform_device_count=N (the only way to get N real
-addressable devices on CPU; the flag must be set before jax init, so it
-cannot run in-process). The driver serves a request wave through a
+--devices N (it asks for N virtual CPU devices, the only way to get N
+real addressable devices on CPU; that must happen before jax
+initializes, so it cannot run in-process). The driver serves a request
+wave through a
 mesh-sharded Scheduler on an N x 1 lane-parallel mesh and asserts every
 stream token-identical to the single-device one-shot oracle BEFORE
 reporting a number — a row in this table is a correctness certificate

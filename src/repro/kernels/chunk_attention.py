@@ -64,16 +64,17 @@ def _chunk_kernel(q_ref, ck_ref, cv_ref, cpos_ref, kk_ref, kv_ref, kp_ref,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     q = q_ref[0].astype(jnp.float32)                       # [bq, D]
-    qpos = qp_ref[0]                                       # [bq] int32
+    qpos = qp_ref[0]                                       # [bq, 1] int32
 
     def accum(k, v, kpos):
-        """One online-softmax step over a key tile; returns the
-        unnormalized prob tile (for the cache-probs reconstruction)."""
+        """One online-softmax step over a key tile (kpos: [1, bk]);
+        returns the unnormalized prob tile (for the cache-probs
+        reconstruction)."""
         s = jax.lax.dot_general(
             q, k.astype(jnp.float32), (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * sm_scale
-        dist = qpos[:, None] - kpos[None, :]
-        mask = (kpos[None, :] >= 0) & (dist >= 0)
+        dist = qpos - kpos
+        mask = (kpos >= 0) & (dist >= 0)
         if window > 0:
             mask = mask & (dist < window)
         s = jnp.where(mask, s, NEG_INF)
@@ -98,7 +99,7 @@ def _chunk_kernel(q_ref, ck_ref, cv_ref, cpos_ref, kk_ref, kv_ref, kp_ref,
             # wrapper rescales by exp(m_blk - m_final)/l_final (flash
             # reconstruction)
             praw_ref[0] = p
-            mblk_ref[0, :, 0] = m_scr[...]
+            mblk_ref[0, 0] = m_scr[...][:, None]
 
     @pl.when(ki >= n_m)
     def _chunk_block():
@@ -110,8 +111,8 @@ def _chunk_kernel(q_ref, ck_ref, cv_ref, cpos_ref, kk_ref, kv_ref, kp_ref,
                     jnp.maximum(l_scr[...], 1e-30)[:, None]
                     ).astype(o_ref.dtype)
         if want_probs:
-            mfin_ref[0] = m_scr[...]
-            lfin_ref[0] = l_scr[...]
+            mfin_ref[0] = m_scr[...][:, None]
+            lfin_ref[0] = l_scr[...][:, None]
 
 
 def chunk_attention_pallas(q, k_c, v_c, cache_k, cache_v, cache_pos,
@@ -143,7 +144,10 @@ def chunk_attention_pallas(q, k_c, v_c, cache_k, cache_v, cache_pos,
     vh = jnp.moveaxis(v_c, 2, 1).reshape(B * Hkv, C, D)
     ck = cache_k.reshape(B * Hkv, M, D)
     cv = cache_v.reshape(B * Hkv, M, D)
-    cp = cache_pos.reshape(B * Hkv, M)
+    # per-row position operands and stat outputs carry a unit dim, so
+    # every block's last two dims equal the array's or are (8, 128)-
+    # aligned — the rule the TPU lowering enforces
+    cp = cache_pos.reshape(B * Hkv, 1, M)
 
     q_block = min(q_block, max(C, 8))
     m_block = min(m_block, max(M, 8))
@@ -158,17 +162,18 @@ def chunk_attention_pallas(q, k_c, v_c, cache_k, cache_v, cache_pos,
     if pm:
         ck = jnp.pad(ck, ((0, 0), (0, pm), (0, 0)))
         cv = jnp.pad(cv, ((0, 0), (0, pm), (0, 0)))
-        cp = jnp.pad(cp, ((0, 0), (0, pm)), constant_values=-1)
+        cp = jnp.pad(cp, ((0, 0), (0, 0), (0, pm)), constant_values=-1)
     if pc:
         kh = jnp.pad(kh, ((0, 0), (0, pc), (0, 0)))
         vh = jnp.pad(vh, ((0, 0), (0, pc), (0, 0)))
-    # chunk positions enter twice: per-q-block (query positions) and
-    # per-c-block (chunk-key positions) — padded with -1 on both axes,
-    # one row per batch element (ragged prompts mark per-request tails)
+    # chunk positions enter twice: per-q-block query positions as a
+    # column [B, Cq, 1] and per-c-block chunk-key positions as a row
+    # [B, 1, Cc] — padded with -1 on both axes, one row per batch
+    # element (ragged prompts mark per-request tails)
     cp2 = jnp.broadcast_to(jnp.atleast_2d(chunk_pos.astype(jnp.int32)),
                            (B, C))
-    qp_q = jnp.pad(cp2, ((0, 0), (0, pq)), constant_values=-1)
-    qp_c = jnp.pad(cp2, ((0, 0), (0, pc)), constant_values=-1)
+    qp_q = jnp.pad(cp2, ((0, 0), (0, pq)), constant_values=-1)[:, :, None]
+    qp_c = jnp.pad(cp2, ((0, 0), (0, pc)), constant_values=-1)[:, None, :]
     Cq, Mp = n_q * q_block, n_m * m_block
 
     kernel = functools.partial(_chunk_kernel, sm_scale=1.0 / np.sqrt(D),
@@ -188,16 +193,16 @@ def chunk_attention_pallas(q, k_c, v_c, cache_k, cache_v, cache_pos,
         out_specs += [
             pl.BlockSpec((1, q_block, m_block),
                          lambda bh, qi, ki: (bh, qi, cache_i(ki))),
-            pl.BlockSpec((1, q_block, 1),
-                         lambda bh, qi, ki: (bh, qi, cache_i(ki))),
-            pl.BlockSpec((1, q_block), lambda bh, qi, ki: (bh, qi)),
-            pl.BlockSpec((1, q_block), lambda bh, qi, ki: (bh, qi)),
+            pl.BlockSpec((1, 1, q_block, 1),
+                         lambda bh, qi, ki: (bh, cache_i(ki), qi, 0)),
+            pl.BlockSpec((1, q_block, 1), lambda bh, qi, ki: (bh, qi, 0)),
+            pl.BlockSpec((1, q_block, 1), lambda bh, qi, ki: (bh, qi, 0)),
         ]
         out_shape += [
             jax.ShapeDtypeStruct((B * Hq, Cq, Mp), jnp.float32),
-            jax.ShapeDtypeStruct((B * Hq, Cq, n_m), jnp.float32),
-            jax.ShapeDtypeStruct((B * Hq, Cq), jnp.float32),
-            jax.ShapeDtypeStruct((B * Hq, Cq), jnp.float32),
+            jax.ShapeDtypeStruct((B * Hq, n_m, Cq, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B * Hq, Cq, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B * Hq, Cq, 1), jnp.float32),
         ]
     res = pl.pallas_call(
         kernel,
@@ -208,15 +213,16 @@ def chunk_attention_pallas(q, k_c, v_c, cache_k, cache_v, cache_pos,
                          lambda bh, qi, ki: (bh // group, cache_i(ki), 0)),
             pl.BlockSpec((1, m_block, D),
                          lambda bh, qi, ki: (bh // group, cache_i(ki), 0)),
-            pl.BlockSpec((1, m_block),
-                         lambda bh, qi, ki: (bh // group, cache_i(ki))),
+            pl.BlockSpec((1, 1, m_block),
+                         lambda bh, qi, ki: (bh // group, 0, cache_i(ki))),
             pl.BlockSpec((1, c_block, D),
                          lambda bh, qi, ki: (bh // group, chunk_i(ki), 0)),
             pl.BlockSpec((1, c_block, D),
                          lambda bh, qi, ki: (bh // group, chunk_i(ki), 0)),
-            pl.BlockSpec((1, c_block),
-                         lambda bh, qi, ki: (bh // Hq, chunk_i(ki))),
-            pl.BlockSpec((1, q_block), lambda bh, qi, ki: (bh // Hq, qi)),
+            pl.BlockSpec((1, 1, c_block),
+                         lambda bh, qi, ki: (bh // Hq, 0, chunk_i(ki))),
+            pl.BlockSpec((1, q_block, 1),
+                         lambda bh, qi, ki: (bh // Hq, qi, 0)),
         ],
         out_specs=out_specs,
         out_shape=out_shape,
@@ -233,6 +239,8 @@ def chunk_attention_pallas(q, k_c, v_c, cache_k, cache_v, cache_pos,
     if not need_probs:
         return out, None
     _, praw, mblk, mfin, lfin = res
+    mblk = jnp.moveaxis(mblk[..., 0], 1, 2)                # [BHq, Cq, n_m]
+    mfin, lfin = mfin[..., 0], lfin[..., 0]
     # flash reconstruction: rescale each cache block's exp(s - m_blk)
     # tile by exp(m_blk - m_fin) and divide by the final denominator
     scale = jnp.exp(jnp.repeat(mblk, m_block, axis=2) - mfin[..., None])

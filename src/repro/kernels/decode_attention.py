@@ -56,7 +56,7 @@ def _decode_kernel(q_ref, k_ref, v_ref, pos_ref, kn_ref, vn_ref, t_ref,
     q = q_ref[0].astype(jnp.float32)                        # [1, D]
     k = k_ref[0].astype(jnp.float32)                        # [bm, D]
     v = v_ref[0].astype(jnp.float32)
-    pos = pos_ref[0]                                        # [bm] int32
+    pos = pos_ref[0]                                        # [1, bm] int32
     # per-lane clock: t is [B] in SMEM (continuous batching runs each
     # lane at its own position); grid dim 0 walks B*Hq rows
     t = t_ref[pl.program_id(0) // hq]
@@ -64,9 +64,9 @@ def _decode_kernel(q_ref, k_ref, v_ref, pos_ref, kn_ref, vn_ref, t_ref,
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
     slot = mi * m_block + jax.lax.broadcasted_iota(jnp.int32, (1, m_block), 1)
-    ok = (pos[None, :] >= 0) & (slot < M)
+    ok = (pos >= 0) & (slot < M)
     if window > 0:
-        ok = ok & ((t - pos[None, :]) < window)
+        ok = ok & ((t - pos) < window)
     s = jnp.where(ok, s, NEG_INF)
 
     m_prev, l_prev = m_scr[...], l_scr[...]
@@ -81,8 +81,8 @@ def _decode_kernel(q_ref, k_ref, v_ref, pos_ref, kn_ref, vn_ref, t_ref,
     m_scr[...] = m_new
     l_scr[...] = l_new
     if want_probs:
-        praw_ref[...] = p
-        mblk_ref[0, 0] = m_new[0]
+        praw_ref[0] = p
+        mblk_ref[0, 0] = m_new[:, None]
 
     @pl.when(mi == n_m - 1)
     def _finish():
@@ -100,13 +100,12 @@ def _decode_kernel(q_ref, k_ref, v_ref, pos_ref, kn_ref, vn_ref, t_ref,
             acc = acc * a[:, None] + p_n[:, None] * v_n
             m_fin = m2
             if want_probs:
-                pn_ref[0, 0] = (p_n /
-                                jnp.maximum(l_fin, 1e-30))[0]
+                pn_ref[0] = (p_n / jnp.maximum(l_fin, 1e-30))[:, None]
         o_ref[0] = (acc / jnp.maximum(l_fin, 1e-30)[:, None]
                     ).astype(o_ref.dtype)
         if want_probs:
-            mfin_ref[0, 0] = m_fin[0]
-            lfin_ref[0, 0] = l_fin[0]
+            mfin_ref[0] = m_fin[:, None]
+            lfin_ref[0] = l_fin[:, None]
 
 
 def decode_attention_pallas(q_t, k_cache, v_cache, pos, t, *, window=0,
@@ -134,7 +133,10 @@ def decode_attention_pallas(q_t, k_cache, v_cache, pos, t, *, window=0,
     qh = q_t.reshape(B * Hq, 1, D)
     kh = k_cache.reshape(B * Hkv, M, D)
     vh = v_cache.reshape(B * Hkv, M, D)
-    ph = pos.reshape(B * Hkv, M)
+    # every per-row operand and output is 3-D (or 4-D) with a unit
+    # middle dim, so each block's last two dims equal the array's or
+    # are (8, 128)-aligned — the rule the TPU lowering enforces
+    ph = pos.reshape(B * Hkv, 1, M)
     if has_new:
         knh = new_kv[0].reshape(B * Hkv, 1, D)
         vnh = new_kv[1].reshape(B * Hkv, 1, D)
@@ -147,7 +149,7 @@ def decode_attention_pallas(q_t, k_cache, v_cache, pos, t, *, window=0,
     if pad:
         kh = jnp.pad(kh, ((0, 0), (0, pad), (0, 0)))
         vh = jnp.pad(vh, ((0, 0), (0, pad), (0, 0)))
-        ph = jnp.pad(ph, ((0, 0), (0, pad)), constant_values=-1)
+        ph = jnp.pad(ph, ((0, 0), (0, 0), (0, pad)), constant_values=-1)
     t_arr = jnp.broadcast_to(jnp.asarray(t, jnp.int32), (B,))
     Mp = n_m * m_block
 
@@ -159,19 +161,17 @@ def decode_attention_pallas(q_t, k_cache, v_cache, pos, t, *, window=0,
     if return_probs:
         # probs outputs only when asked: a needs_attn=False serving path
         # skips the O(M) f32 praw writes entirely
+        row = pl.BlockSpec((1, 1, 1), lambda bh, mi: (bh, 0, 0))
         out_specs += [
-            pl.BlockSpec((1, m_block), lambda bh, mi: (bh, mi)),
-            pl.BlockSpec((1, 1), lambda bh, mi: (bh, mi)),
-            pl.BlockSpec((1, 1), lambda bh, mi: (bh, 0)),
-            pl.BlockSpec((1, 1), lambda bh, mi: (bh, 0)),
-            pl.BlockSpec((1, 1), lambda bh, mi: (bh, 0)),
+            pl.BlockSpec((1, 1, m_block), lambda bh, mi: (bh, 0, mi)),
+            pl.BlockSpec((1, 1, 1, 1), lambda bh, mi: (bh, mi, 0, 0)),
+            row, row, row,
         ]
+        one = jax.ShapeDtypeStruct((B * Hq, 1, 1), jnp.float32)
         out_shape += [
-            jax.ShapeDtypeStruct((B * Hq, Mp), jnp.float32),
-            jax.ShapeDtypeStruct((B * Hq, n_m), jnp.float32),
-            jax.ShapeDtypeStruct((B * Hq, 1), jnp.float32),
-            jax.ShapeDtypeStruct((B * Hq, 1), jnp.float32),
-            jax.ShapeDtypeStruct((B * Hq, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B * Hq, 1, Mp), jnp.float32),
+            jax.ShapeDtypeStruct((B * Hq, n_m, 1, 1), jnp.float32),
+            one, one, one,
         ]
     res = pl.pallas_call(
         kernel,
@@ -180,7 +180,8 @@ def decode_attention_pallas(q_t, k_cache, v_cache, pos, t, *, window=0,
             pl.BlockSpec((1, 1, D), lambda bh, mi: (bh, 0, 0)),
             pl.BlockSpec((1, m_block, D), lambda bh, mi: (bh // group, mi, 0)),
             pl.BlockSpec((1, m_block, D), lambda bh, mi: (bh // group, mi, 0)),
-            pl.BlockSpec((1, m_block), lambda bh, mi: (bh // group, mi)),
+            pl.BlockSpec((1, 1, m_block),
+                         lambda bh, mi: (bh // group, 0, mi)),
             pl.BlockSpec((1, 1, D), lambda bh, mi: (bh // group, 0, 0)),
             pl.BlockSpec((1, 1, D), lambda bh, mi: (bh // group, 0, 0)),
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -198,6 +199,9 @@ def decode_attention_pallas(q_t, k_cache, v_cache, pos, t, *, window=0,
         return res[0].reshape(B, Hq, D)
     out, praw, mblk, mfin, lfin, p_new = res
     out = out.reshape(B, Hq, D)
+    praw = praw.reshape(B * Hq, Mp)
+    mblk = mblk.reshape(B * Hq, n_m)
+    mfin, lfin = mfin.reshape(B * Hq, 1), lfin.reshape(B * Hq, 1)
     # flash-style reconstruction: rescale each block's exp(s - m_block)
     # tile by exp(m_block - m_final) and divide by the final denominator
     scale = jnp.exp(jnp.repeat(mblk, m_block, axis=1) - mfin)

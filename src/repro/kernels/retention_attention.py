@@ -55,8 +55,8 @@ def _flash_kernel(off_ref, q_ref, k_ref, v_ref, lb_ref, o_ref, m_scr,
     if window > 0:
         mask = mask & (dist < window)
     if use_beta:
-        lb = lb_ref[0].astype(jnp.float32)                 # [bk]
-        s = s + jnp.where(mask, dist.astype(jnp.float32) * lb[None, :], 0.0)
+        lb = lb_ref[0].astype(jnp.float32)                 # [1, bk]
+        s = s + jnp.where(mask, dist.astype(jnp.float32) * lb, 0.0)
     s = jnp.where(mask, s, NEG_INF)
 
     m_prev, l_prev = m_scr[...], l_scr[...]
@@ -93,7 +93,10 @@ def retention_attention_pallas(q, k, v, log_beta=None, *, causal=True,
     qh = jnp.moveaxis(q, 2, 1).reshape(B * Hq, Tq, D)
     kh = jnp.moveaxis(k, 2, 1).reshape(B * Hkv, Tk, D)
     vh = jnp.moveaxis(v, 2, 1).reshape(B * Hkv, Tk, D)
-    lbh = jnp.moveaxis(log_beta, 2, 1).reshape(B * Hkv, Tk)
+    # [B*Hkv, 1, Tk]: the unit middle dim keeps the log_beta block's
+    # last two dims equal to the array's or (8, 128)-aligned, as the TPU
+    # lowering requires
+    lbh = jnp.moveaxis(log_beta, 2, 1).reshape(B * Hkv, 1, Tk)
 
     q_block = min(q_block, max(Tq, 8))
     kv_block = min(kv_block, max(Tk, 8))
@@ -105,7 +108,7 @@ def retention_attention_pallas(q, k, v, log_beta=None, *, causal=True,
     if pk:
         kh = jnp.pad(kh, ((0, 0), (0, pk), (0, 0)))
         vh = jnp.pad(vh, ((0, 0), (0, pk), (0, 0)))
-        lbh = jnp.pad(lbh, ((0, 0), (0, pk)))
+        lbh = jnp.pad(lbh, ((0, 0), (0, 0), (0, pk)))
 
     kernel = functools.partial(
         _flash_kernel, sm_scale=1.0 / np.sqrt(D), causal=causal,
@@ -124,8 +127,8 @@ def retention_attention_pallas(q, k, v, log_beta=None, *, causal=True,
                          lambda bh, qi, ki: (bh // group, ki, 0)),
             pl.BlockSpec((1, kv_block, D),
                          lambda bh, qi, ki: (bh // group, ki, 0)),
-            pl.BlockSpec((1, kv_block),
-                         lambda bh, qi, ki: (bh // group, ki)),
+            pl.BlockSpec((1, 1, kv_block),
+                         lambda bh, qi, ki: (bh // group, 0, ki)),
         ],
         out_specs=pl.BlockSpec((1, q_block, D),
                                lambda bh, qi, ki: (bh, qi, 0)),

@@ -4,12 +4,18 @@ Functions, not module constants: importing this module must never touch
 jax device state (smoke tests run with 1 CPU device; only dryrun.py sets
 XLA_FLAGS for 512 host devices).
 
+Every mesh is built with Auto axis types: the sharding rules and the
+serving programs are written for GSPMD propagation, while
+`jax.make_mesh` defaults to Explicit axes (sharding in types), under
+which e.g. the embedding gather refuses a vocab-sharded operand.
+
 Hardware model (TPU v5e-class, used by the roofline):
   197 TFLOP/s bf16 per chip; 819 GB/s HBM; ~50 GB/s/link ICI.
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 PEAK_FLOPS = 197e12          # bf16 / chip
 HBM_BW = 819e9               # bytes/s / chip
@@ -22,33 +28,39 @@ MULTI_POD = (2, 16, 16)      # 512 chips
 def make_production_mesh(*, multi_pod: bool = False):
     shape = MULTI_POD if multi_pod else SINGLE_POD
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Degenerate 1x1 mesh over the single local device (smoke scale)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return _auto_mesh((1, 1), ("data", "model"))
 
 
-def make_cpu_mesh(data: int, model: int):
-    """REAL (data, model) mesh over virtual host devices — for sharded
-    serving validation on CPU (launch/shard_serve.py, tests). Unlike the
+def make_serving_mesh(data: int, model: int):
+    """REAL (data, model) mesh over the first data*model local devices:
+    the chips of a TPU host, or virtual CPU devices for sharded-serving
+    validation off the chip (launch/shard_serve.py, tests). Unlike the
     abstract device-duplicating test meshes, every position is a
     distinct addressable device, so programs actually SPMD-partition
-    and execute. Requires the process to have been launched with
-    --xla_force_host_platform_device_count >= data*model set BEFORE jax
-    initialized (the dryrun.py pattern); raises a clear error
-    otherwise instead of silently building a broken mesh."""
+    and execute. On the CPU the process needs that many virtual devices
+    before jax initializes (shard_serve.main sets jax_num_cpu_devices);
+    raises a clear error otherwise instead of building a broken mesh."""
     need = data * model
     have = len(jax.devices())
     if have < need:
         raise RuntimeError(
-            f"make_cpu_mesh({data}, {model}) needs {need} devices but "
-            f"only {have} are visible. Set XLA_FLAGS="
-            f"\"--xla_force_host_platform_device_count={need}\" in the "
-            f"environment (or as the process's first statement) before "
-            f"jax initializes — see launch/shard_serve.py.")
-    return jax.make_mesh((data, model), ("data", "model"))
+            f"make_serving_mesh({data}, {model}) needs {need} devices "
+            f"but only {have} are visible (on the CPU, set "
+            f"jax_num_cpu_devices >= {need} before jax initializes — "
+            f"see launch/shard_serve.py).")
+    return _auto_mesh((data, model), ("data", "model"),
+                      devices=jax.devices()[:need])
+
+
+def _auto_mesh(shape, axes, devices=None):
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def num_chips(mesh) -> int:

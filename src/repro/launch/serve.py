@@ -33,6 +33,7 @@ import numpy as np
 
 from repro.configs import ARCH_IDS, get_config, get_smoke_config
 from repro.data.synthetic import make_batch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as T
 from repro.serve import FaultInjector, Request, Scheduler, build_engine
 from repro.serve.request import latency_percentiles
@@ -379,6 +380,7 @@ def main():
                          "positions verify in one chunk-shaped "
                          "dispatch, outputs stay token-identical")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     key = jax.random.PRNGKey(args.seed)

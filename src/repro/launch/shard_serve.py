@@ -1,10 +1,12 @@
 """Sharded-serving validation driver: SPMD serving on virtual CPU
 devices (docs/serving.md §Sharded serving).
 
-Run as a SUBPROCESS (the dryrun.py pattern): XLA_FLAGS must be set
-before jax initializes, so the --devices flag is consumed by the FIRST
-statements of this module, before the jax import. tests/test_shard_serve
-and benchmarks/table14_shard both spawn it and parse the JSON it prints.
+Run as its own process: main() pins the CPU backend and asks for
+--devices virtual devices before jax initializes, so the caller's
+process keeps its own backend. tests/test_shard_serve and
+benchmarks/table14_shard both spawn it and parse the JSON it prints.
+Importing this module sets nothing: the chip smoke test reuses
+run_check_hlo on real devices.
 
 Modes (one JSON document on stdout either way):
   default        parity matrix: for each requested mesh (e.g. 8x1
@@ -29,39 +31,19 @@ Modes (one JSON document on stdout either way):
                  (single device): the scan-over-layers residual
                  measurement referenced by docs/serving.md.
 """
-import os
-import sys
+import argparse
+import dataclasses
+import json
+import time
 
+import jax
+import jax.numpy as jnp
+import numpy as np
 
-def _flag(name: str, default: str) -> str:
-    for i, a in enumerate(sys.argv):
-        if a == name and i + 1 < len(sys.argv):
-            return sys.argv[i + 1]
-        if a.startswith(name + "="):
-            return a.split("=", 1)[1]
-    return default
-
-
-_N_DEV = int(_flag("--devices", "8"))
-os.environ["XLA_FLAGS"] = (
-    f"--xla_force_host_platform_device_count={_N_DEV} "
-    + os.environ.get("XLA_FLAGS", ""))
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
-import argparse                                              # noqa: E402
-import dataclasses                                           # noqa: E402
-import json                                                  # noqa: E402
-import time                                                  # noqa: E402
-
-import jax                                                   # noqa: E402
-import jax.numpy as jnp                                      # noqa: E402
-import numpy as np                                           # noqa: E402
-
-from repro.configs import get_smoke_config                   # noqa: E402
-from repro.launch.mesh import make_cpu_mesh                  # noqa: E402
-from repro.models import transformer as T                    # noqa: E402
-from repro.serve import (Request, Scheduler, Status,         # noqa: E402
-                         build_engine)
+from repro.configs import get_smoke_config
+from repro.launch.mesh import make_serving_mesh
+from repro.models import transformer as T
+from repro.serve import Request, Scheduler, Status, build_engine
 
 # head counts chosen to DIVIDE the 1x8 head-parallel mesh (8 MHA heads)
 # while the 8x1 mesh shards the lane axis instead — the two prod-mesh
@@ -132,7 +114,7 @@ def _check(res, reqs, oracle, policy, label):
 
 def run_parity(mesh_shape, policies, oracle, cfg, params, gates,
                serve_kw, n_lanes):
-    mesh = make_cpu_mesh(*mesh_shape)
+    mesh = make_serving_mesh(*mesh_shape)
     cases = []
     engines = {}
     reqs_spec = ([5, 11, 19, 8, 14, 23], [6, 3, 8, 5, 7, 4])
@@ -238,7 +220,7 @@ def run_bench(devices, oracle, cfg, params, gates, serve_kw, n_lanes):
     """One table14_shard point: lane-parallel (devices x 1) mesh,
     compile time (scheduler build + first step) and steady-state
     decode throughput over a drain, parity asserted."""
-    mesh = make_cpu_mesh(devices, 1) if devices > 1 else None
+    mesh = make_serving_mesh(devices, 1) if devices > 1 else None
     policy = "trimkv"
     eng = build_engine(cfg, params, gates, mesh=mesh, policy=policy,
                        **serve_kw)
@@ -267,29 +249,27 @@ def run_bench(devices, oracle, cfg, params, gates, serve_kw, n_lanes):
 _RESHARD_COLLECTIVES = ("all-gather", "all-to-all", "collective-permute")
 
 
-def run_check_hlo(mesh_shape, cfg, params, gates, serve_kw, n_lanes):
-    """Compile the hot-loop closures on the lane-parallel mesh and
-    assert the OPTIMIZED HLO has no cross-shard resharding collective —
-    lane-aligned operands + mask-select installs keep every program
-    shard-local on the lane axis (scalar all-reduce, e.g. a global
-    any() on health flags, is tolerated: it moves O(1) bytes)."""
-    mesh = make_cpu_mesh(*mesh_shape)
-    eng = build_engine(cfg, params, gates, mesh=mesh, policy="trimkv",
-                       **serve_kw)
+def run_check_hlo(eng, n_lanes):
+    """Compile the hot-loop closures of `eng` (an Engine on a
+    lane-parallel mesh) and assert the OPTIMIZED HLO has no cross-shard
+    resharding collective — lane-aligned operands + mask-select
+    installs keep every program shard-local on the lane axis (scalar
+    all-reduce, e.g. a global any() on health flags, is tolerated: it
+    moves O(1) bytes)."""
     cl = eng.lane_closures(True, n_lanes)
     state = eng.fresh_state(n_lanes)
     tok = jnp.zeros((n_lanes,), jnp.int32)
     keys = jnp.zeros((n_lanes, 2), jnp.uint32)
     bmask = jnp.zeros((n_lanes,), bool)
     i32 = jnp.zeros((n_lanes,), jnp.int32)
-    C = serve_kw.get("prefill_chunk", 8)
+    C, n = eng.serve.prefill_chunk, eng.serve.decode_segment
     chunks = jnp.zeros((2, n_lanes, C), jnp.int32)
     nv = jnp.zeros((2, n_lanes), jnp.int32)
     report = {}
     progs = {
         "segment": (cl["segment"],
-                    (state, tok, keys, bmask, i32, i32, i32, 4,
-                     np.int32(4))),
+                    (state, tok, keys, bmask, i32, i32, i32, n,
+                     np.int32(n))),
         "admit": (cl["admit"],
                   (state, tok, keys, chunks, nv, keys, bmask)),
         "resume": (cl["resume"],
@@ -304,7 +284,8 @@ def run_check_hlo(mesh_shape, cfg, params, gates, serve_kw, n_lanes):
         report[name] = found
         assert not found, (
             f"{name} HLO contains cross-shard resharding: {found}")
-    return {"mesh": list(mesh_shape), "programs": list(progs),
+    return {"mesh": list(eng.mesh.shape.values()),
+            "programs": list(progs),
             "resharding_collectives": report, "ok": True}
 
 
@@ -349,6 +330,10 @@ def main():
     ap.add_argument("--compile-depth", action="store_true")
     ap.add_argument("--n-lanes", type=int, default=8)
     args = ap.parse_args()
+    # virtual CPU devices: set before the first backend use, so only
+    # this process (spawned by its callers) is affected
+    jax.config.update("jax_platforms", "cpu")
+    jax.config.update("jax_num_cpu_devices", args.devices)
 
     serve_kw = dict(budget=16, prefill_chunk=8, decode_segment=4)
     cfg = smoke_cfg()
@@ -365,8 +350,10 @@ def main():
         out["mode"] = "check-hlo"
         mesh_shape = tuple(
             int(x) for x in args.meshes.split(",")[0].split("x"))
-        out.update(run_check_hlo(mesh_shape, cfg, params, gates,
-                                 serve_kw, args.n_lanes))
+        eng = build_engine(cfg, params, gates,
+                           mesh=make_serving_mesh(*mesh_shape),
+                           policy="trimkv", **serve_kw)
+        out.update(run_check_hlo(eng, args.n_lanes))
     elif args.bench:
         out["mode"] = "bench"
         out.update(run_bench(args.devices, oracle, cfg, params, gates,

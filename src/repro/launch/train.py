@@ -15,6 +15,7 @@ import argparse
 from repro.configs import ARCH_IDS, TrainConfig, get_config, \
     get_smoke_config
 from repro.data import DataConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.train.trainer import train_loop
 
 
@@ -32,6 +33,7 @@ def main():
                              "procedural", "mixed"))
     ap.add_argument("--ckpt", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     train_cfg = TrainConfig(global_batch=args.batch, seq_len=args.seq,

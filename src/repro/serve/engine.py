@@ -54,25 +54,55 @@ from repro.serve.prefix_cache import PrefixCache
 from repro.sharding import rules as shard_rules
 
 
+def _greedy_token(params, cfg, h_last):
+    """Greedy token from the prefill's last hidden state [B,d]."""
+    logits = T.compute_logits(params, cfg, h_last)
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
+class _Weighted:
+    """A jitted program whose two leading arguments are the weights and
+    gates, called with the rest: it supplies the engine's arrays, so the
+    weights reach the device program as arguments (counted in its
+    argument bytes), never as constants compiled into its HLO."""
+
+    def __init__(self, engine, jitted):
+        self.engine, self.jitted = engine, jitted
+
+    def __call__(self, *args):
+        return self.jitted(self.engine.params, self.engine.gates, *args)
+
+    def lower(self, *args):
+        return self.jitted.lower(self.engine.params, self.engine.gates,
+                                 *args)
+
+    def _cache_size(self):
+        return self.jitted._cache_size()
+
+
 class Engine:
     def __init__(self, cfg, params, gate_params, serve_cfg: ServeConfig,
                  mesh=None):
         self.cfg = cfg
         self.mesh = mesh
+        # shardings of (params, gates): None on one device
+        self._weight_shardings = None
         if mesh is not None:
             # SPMD serving (docs/serving.md §Sharded serving): weights
             # are placed ONCE — tensor-parallel over "model" where the
             # head/FFN counts divide, replicated over the data axes
             # (fsdp=False: decode must not all-gather weights every
-            # step). Every closure below captures the committed arrays,
-            # so the partitioner sees their layout without per-call
-            # traffic.
+            # step). Every program below takes the committed arrays as
+            # arguments with these shardings, so the partitioner sees
+            # their layout without per-call traffic.
             q_tp, kv_tp = shard_rules.attn_tp_flags(cfg, mesh)
-            params = jax.device_put(
-                params, shard_rules.param_shardings(
-                    mesh, params, fsdp=False, q_tp=q_tp, kv_tp=kv_tp))
-            gate_params = jax.device_put(
-                gate_params, shard_rules.replicated(mesh, gate_params))
+            self._weight_shardings = (
+                shard_rules.param_shardings(mesh, params, fsdp=False,
+                                            q_tp=q_tp, kv_tp=kv_tp),
+                shard_rules.replicated(mesh, gate_params))
+            params = jax.device_put(params, self._weight_shardings[0])
+            gate_params = jax.device_put(gate_params,
+                                         self._weight_shardings[1])
         self.params = params
         self.gates = gate_params
         self.serve = serve_cfg
@@ -92,46 +122,51 @@ class Engine:
                 "capacity couples tokens across the verify chunk, so "
                 "chunk-shaped scoring cannot be bit-identical per row")
 
-        def _prefill(tokens, state, extra):
-            return T.prefill(params, gate_params, cfg, tokens, state,
+        # every program that reads the weights takes them as its two
+        # leading ARGUMENTS (see _Weighted): a closed-over array would be
+        # lowered as an HLO literal, baking the weights into each program
+        def _prefill(params, gates, tokens, state, extra):
+            return T.prefill(params, gates, cfg, tokens, state,
                              self.policy, serve_cfg, extra_inputs=extra)
 
-        def _prefill_chunk(tokens, n_valid, state, extra):
-            return T.prefill_chunk(params, gate_params, cfg, tokens, state,
+        def _prefill_chunk(params, gates, tokens, n_valid, state, extra):
+            return T.prefill_chunk(params, gates, cfg, tokens, state,
                                    self.policy, serve_cfg, n_valid=n_valid,
                                    extra_inputs=extra)
 
-        def _prefill_chunk_loop(chunks, n_valid, state, extra):
-            return T.prefill_chunk_loop(params, gate_params, cfg, chunks,
+        def _prefill_chunk_loop(params, gates, chunks, n_valid, state,
+                                extra):
+            return T.prefill_chunk_loop(params, gates, cfg, chunks,
                                         n_valid, state, self.policy,
                                         serve_cfg, extra_inputs=extra)
 
-        def _decode(state, token):
-            return T.decode_step(params, gate_params, cfg, state, token,
+        def _decode(params, gates, state, token):
+            return T.decode_step(params, gates, cfg, state, token,
                                  self.policy, attn_impl=impl)
 
-        def _decode_loop(state, h_last, rng, n_steps, greedy):
-            first = self._first_token(h_last)
-            return T.decode_loop(params, gate_params, cfg, state, first,
+        def _decode_loop(params, gates, state, h_last, rng, n_steps,
+                         greedy):
+            first = _greedy_token(params, cfg, h_last)
+            return T.decode_loop(params, gates, cfg, state, first,
                                  n_steps, self.policy, greedy=greedy,
                                  temperature=serve_cfg.temperature,
                                  rng=rng, attn_impl=impl)
 
-        def _tf_loop(state, h_last, tokens):
-            preds0 = self._first_token(h_last)
-            state, preds = T.teacher_force_loop(params, gate_params, cfg,
-                                                state, tokens, self.policy,
+        def _tf_loop(params, gates, state, h_last, tokens):
+            preds0 = _greedy_token(params, cfg, h_last)
+            state, preds = T.teacher_force_loop(params, gates, cfg, state,
+                                                tokens, self.policy,
                                                 attn_impl=impl)
             return state, jnp.concatenate([preds0[:, None], preds], axis=1)
 
-        self._prefill = jax.jit(_prefill, donate_argnums=(1,))
-        self._prefill_chunk = jax.jit(_prefill_chunk, donate_argnums=(2,))
-        self._prefill_chunk_loop = jax.jit(_prefill_chunk_loop,
-                                           donate_argnums=(2,))
-        self._decode = jax.jit(_decode, donate_argnums=(0,))
-        self._decode_loop = jax.jit(_decode_loop, static_argnums=(3, 4),
-                                    donate_argnums=(0,))
-        self._tf_loop = jax.jit(_tf_loop, donate_argnums=(0,))
+        self._prefill = self._weighted(_prefill, donate=(1,))
+        self._prefill_chunk = self._weighted(_prefill_chunk, donate=(2,))
+        self._prefill_chunk_loop = self._weighted(_prefill_chunk_loop,
+                                                  donate=(2,))
+        self._decode = self._weighted(_decode, donate=(0,))
+        self._decode_loop = self._weighted(_decode_loop, static=(3, 4),
+                                           donate=(0,))
+        self._tf_loop = self._weighted(_tf_loop, donate=(0,))
         self._lane_closures = {}
         # prefix KV cache (docs/serving.md §Prefix cache): owned by the
         # ENGINE, not the scheduler, so successive schedulers built on
@@ -189,12 +224,12 @@ class Engine:
         cache_key = (greedy, n_lanes if self.mesh is not None else None)
         if cache_key in self._lane_closures:
             return self._lane_closures[cache_key]
-        params, gates, cfg = self.params, self.gates, self.cfg
+        cfg = self.cfg
         serve, policy, impl = self.serve, self.policy, self.serve.attn_impl
         mem_key = self.mem_key
 
-        def _admit_core(state, tok, keys, chunks, n_valid, new_keys,
-                        lane_mask, extra):
+        def _admit_core(params, gates, state, tok, keys, chunks, n_valid,
+                        new_keys, lane_mask, extra):
             # the WHOLE admission is one program: fresh sub-state +
             # (cross-memory install +) ragged prefill + first tokens +
             # masked lane install — one dispatch per admission round
@@ -213,8 +248,8 @@ class Engine:
             return (state, jnp.where(lane_mask, first, tok),
                     jnp.where(lane_mask[:, None], new_keys, keys))
 
-        def _segment(state, tok, keys, active, n_emitted, max_new, eos,
-                     n_steps, n_real):
+        def _segment(params, gates, state, tok, keys, active, n_emitted,
+                     max_new, eos, n_steps, n_real):
             # n_steps is STATIC (the scan length) but the scheduler
             # rounds it up to power-of-two BUCKETS and passes the
             # logical length as the TRACED n_real (tail steps masked
@@ -227,9 +262,9 @@ class Engine:
                 temperature=serve.temperature, attn_impl=impl,
                 n_real=n_real)
 
-        def _mixed_core(state, tok, keys, active, n_emitted, max_new,
-                        eos, chunks, chunk_valid, finish, new_keys,
-                        mem_inputs, mem_install):
+        def _mixed_core(params, gates, state, tok, keys, active, n_emitted,
+                        max_new, eos, chunks, chunk_valid, finish,
+                        new_keys, mem_inputs, mem_install):
             # interleaved prefill/decode segment (SLO scheduling): the
             # admission prefill — cross-memory install included — rides
             # INSIDE the decode segment, one chunk per admitting lane
@@ -242,42 +277,43 @@ class Engine:
                 temperature=serve.temperature, attn_impl=impl,
                 mem_inputs=mem_inputs, mem_install=mem_install)
 
-        def _mixed_plain(state, tok, keys, active, n_emitted, max_new,
-                         eos, chunks, chunk_valid, finish, new_keys):
+        def _mixed_plain(params, gates, state, tok, keys, active,
+                         n_emitted, max_new, eos, chunks, chunk_valid,
+                         finish, new_keys):
             # mixed segment WITHOUT memory operands — the only mixed
             # closure for self-attention families, and the no-install
             # fast path for cross families (segments where no lane's
             # first chunk rides: skips re-running the encoder/vision
             # projection over the slab just to where-keep old state)
-            return _mixed_core(state, tok, keys, active, n_emitted,
-                               max_new, eos, chunks, chunk_valid,
-                               finish, new_keys, None, None)
+            return _mixed_core(params, gates, state, tok, keys, active,
+                               n_emitted, max_new, eos, chunks,
+                               chunk_valid, finish, new_keys, None, None)
 
         if mem_key is None:
-            def _admit(state, tok, keys, chunks, n_valid, new_keys,
-                       lane_mask):
-                return _admit_core(state, tok, keys, chunks, n_valid,
-                                   new_keys, lane_mask, None)
+            def _admit(params, gates, state, tok, keys, chunks, n_valid,
+                       new_keys, lane_mask):
+                return _admit_core(params, gates, state, tok, keys, chunks,
+                                   n_valid, new_keys, lane_mask, None)
 
             _mixed = _mixed_plain
         else:
-            def _admit(state, tok, keys, chunks, n_valid, new_keys,
-                       lane_mask, mem, mem_len):
-                return _admit_core(state, tok, keys, chunks, n_valid,
-                                   new_keys, lane_mask,
+            def _admit(params, gates, state, tok, keys, chunks, n_valid,
+                       new_keys, lane_mask, mem, mem_len):
+                return _admit_core(params, gates, state, tok, keys, chunks,
+                                   n_valid, new_keys, lane_mask,
                                    {mem_key: mem, "mem_len": mem_len})
 
-            def _mixed(state, tok, keys, active, n_emitted, max_new,
-                       eos, chunks, chunk_valid, finish, new_keys, mem,
-                       mem_len, install):
-                return _mixed_core(state, tok, keys, active, n_emitted,
-                                   max_new, eos, chunks, chunk_valid,
-                                   finish, new_keys,
+            def _mixed(params, gates, state, tok, keys, active, n_emitted,
+                       max_new, eos, chunks, chunk_valid, finish, new_keys,
+                       mem, mem_len, install):
+                return _mixed_core(params, gates, state, tok, keys, active,
+                                   n_emitted, max_new, eos, chunks,
+                                   chunk_valid, finish, new_keys,
                                    {mem_key: mem, "mem_len": mem_len},
                                    install)
 
-        def _admit_prefix(state, tok, keys, chunks, n_valid, new_keys,
-                          lane_mask, sub0, capture_chunk):
+        def _admit_prefix(params, gates, state, tok, keys, chunks, n_valid,
+                          new_keys, lane_mask, sub0, capture_chunk):
             # prefix-cache admission (docs/serving.md §Prefix cache):
             # sub0 carries the lanes' INITIAL sub-state — cached slabs
             # at hit lanes' rows (their per-lane t already at the
@@ -298,14 +334,15 @@ class Engine:
             return (state, jnp.where(lane_mask, first, tok),
                     jnp.where(lane_mask[:, None], new_keys, keys), snap)
 
-        def _admit_capture(state, tok, keys, chunks, n_valid, new_keys,
-                           lane_mask, capture_chunk):
+        def _admit_capture(params, gates, state, tok, keys, chunks, n_valid,
+                           new_keys, lane_mask, capture_chunk):
             # capture-only variant (no hits this round): fresh
             # sub-state built on device, so the host skips shipping a
             # [n_lanes]-row sub0 it would only fill with zeros
             sub0 = T.init_decode_state(cfg, chunks.shape[1], serve.budget)
-            return _admit_prefix(state, tok, keys, chunks, n_valid,
-                                 new_keys, lane_mask, sub0, capture_chunk)
+            return _admit_prefix(params, gates, state, tok, keys, chunks,
+                                 n_valid, new_keys, lane_mask, sub0,
+                                 capture_chunk)
 
         def _prefix_install(state, sub, lane_mask):
             # interleaved-mode prefix hit: where-select the cached
@@ -315,8 +352,8 @@ class Engine:
             # finish transition.
             return T.install_lanes(state, sub, lane_mask)
 
-        def _spec_segment(state, tok, keys, active, n_emitted, max_new,
-                          eos, hist, n_rounds, n_real):
+        def _spec_segment(params, gates, state, tok, keys, active,
+                          n_emitted, max_new, eos, hist, n_rounds, n_real):
             # speculative decode segment (docs/serving.md §Speculative
             # decoding): n_rounds draft/verify rounds, each committing
             # 1..spec_k+1 tokens per live lane in ONE chunk-shaped
@@ -327,9 +364,10 @@ class Engine:
                 max_new, eos, hist, n_rounds, policy,
                 spec_k=serve.spec_k, attn_impl=impl, n_real=n_real)
 
-        def _spec_mixed_core(state, tok, keys, active, n_emitted,
-                             max_new, eos, hist, chunks, chunk_valid,
-                             finish, new_keys, mem_inputs, mem_install):
+        def _spec_mixed_core(params, gates, state, tok, keys, active,
+                             n_emitted, max_new, eos, hist, chunks,
+                             chunk_valid, finish, new_keys, mem_inputs,
+                             mem_install):
             return T.spec_mixed_step_loop(
                 params, gates, cfg, state, tok, keys, active, n_emitted,
                 max_new, eos, hist, chunks, chunk_valid, finish,
@@ -337,24 +375,25 @@ class Engine:
                 attn_impl=impl, mem_inputs=mem_inputs,
                 mem_install=mem_install)
 
-        def _spec_mixed_plain(state, tok, keys, active, n_emitted,
-                              max_new, eos, hist, chunks, chunk_valid,
-                              finish, new_keys):
-            return _spec_mixed_core(state, tok, keys, active, n_emitted,
-                                    max_new, eos, hist, chunks,
+        def _spec_mixed_plain(params, gates, state, tok, keys, active,
+                              n_emitted, max_new, eos, hist, chunks,
+                              chunk_valid, finish, new_keys):
+            return _spec_mixed_core(params, gates, state, tok, keys, active,
+                                    n_emitted, max_new, eos, hist, chunks,
                                     chunk_valid, finish, new_keys,
                                     None, None)
 
         if mem_key is None:
             _spec_mixed = _spec_mixed_plain
         else:
-            def _spec_mixed(state, tok, keys, active, n_emitted,
-                            max_new, eos, hist, chunks, chunk_valid,
-                            finish, new_keys, mem, mem_len, install):
+            def _spec_mixed(params, gates, state, tok, keys, active,
+                            n_emitted, max_new, eos, hist, chunks,
+                            chunk_valid, finish, new_keys, mem, mem_len,
+                            install):
                 return _spec_mixed_core(
-                    state, tok, keys, active, n_emitted, max_new, eos,
-                    hist, chunks, chunk_valid, finish, new_keys,
-                    {mem_key: mem, "mem_len": mem_len}, install)
+                    params, gates, state, tok, keys, active, n_emitted,
+                    max_new, eos, hist, chunks, chunk_valid, finish,
+                    new_keys, {mem_key: mem, "mem_len": mem_len}, install)
 
         def _extract(state, tok, keys):
             # swap-out / checkpoint: ONE dispatch commits the complete
@@ -385,6 +424,7 @@ class Engine:
         # axis only; broadcast to every "model" shard), scalars
         # replicated. Donation is preserved: donated state in/out carry
         # the identical sharding tree, so buffers are reused in place.
+        # The weights' own shardings are prepended by _weighted.
         sh = {}
         if self.mesh is not None:
             mesh = self.mesh
@@ -429,33 +469,36 @@ class Engine:
                 "spec_mixed_nomem": (spec_mixed_in, spec_out),
             }
 
-        def _jit(name, fn, donate=(), static=()):
+        def _jit(name, fn, donate=()):
+            # weight-free programs (lane reset / extract / install)
             kw = {}
-            if static:
-                kw["static_argnums"] = static
             if donate:
                 kw["donate_argnums"] = donate
             if name in sh:
                 kw["in_shardings"], kw["out_shardings"] = sh[name]
             return jax.jit(fn, **kw)
 
-        mixed_jit = _jit("mixed", _mixed, donate=(0,))
+        def _wjit(name, fn, donate=(), static=()):
+            return self._weighted(fn, donate=donate, static=static,
+                                  shardings=sh.get(name))
+
+        mixed_jit = _wjit("mixed", _mixed, donate=(0,))
         # speculative closures exist only where speculation is legal:
         # spec_k > 0 and GREEDY (stochastic verification cannot
         # reproduce the per-lane key chain bit-identically)
         spec_on = serve.spec_k > 0 and greedy
-        spec_mixed_jit = (_jit("spec_mixed", _spec_mixed, donate=(0,))
+        spec_mixed_jit = (_wjit("spec_mixed", _spec_mixed, donate=(0,))
                           if spec_on else None)
         closures = {
-            "admit": _jit("admit", _admit, donate=(0,)),
-            "segment": _jit("segment", _segment, static=(7,),
-                            donate=(0,)),
+            "admit": _wjit("admit", _admit, donate=(0,)),
+            "segment": _wjit("segment", _segment, static=(7,),
+                             donate=(0,)),
             "mixed": mixed_jit,
             # same jit object for non-cross families: _mixed IS the
             # plain closure there, so no second compilation cache
             "mixed_nomem": (mixed_jit if mem_key is None else
-                            _jit("mixed_nomem", _mixed_plain,
-                                 donate=(0,))),
+                            _wjit("mixed_nomem", _mixed_plain,
+                                  donate=(0,))),
             "reset": _jit("reset", T.reset_lanes, donate=(0,)),
             "extract": _jit("extract", _extract),
             "resume": _jit("resume", _resume, donate=(0,)),
@@ -465,31 +508,45 @@ class Engine:
             # scheduler bypasses the cache for cross-memory families
             # (a cached slab would not carry the encoder/vision memory
             # its suffix chunks cross-attend into)
-            "admit_prefix": (_jit("admit_prefix", _admit_prefix,
-                                  donate=(0,))
-                             if mem_key is None else None),
-            "admit_capture": (_jit("admit_capture", _admit_capture,
+            "admit_prefix": (_wjit("admit_prefix", _admit_prefix,
                                    donate=(0,))
+                             if mem_key is None else None),
+            "admit_capture": (_wjit("admit_capture", _admit_capture,
+                                    donate=(0,))
                               if mem_key is None else None),
             "prefix_install": (_jit("prefix_install", _prefix_install,
                                     donate=(0,))
                                if mem_key is None else None),
-            "spec_segment": (_jit("spec_segment", _spec_segment,
-                                  static=(8,), donate=(0,))
+            "spec_segment": (_wjit("spec_segment", _spec_segment,
+                                   static=(8,), donate=(0,))
                              if spec_on else None),
             "spec_mixed": spec_mixed_jit,
             "spec_mixed_nomem": (
                 spec_mixed_jit if (mem_key is None or not spec_on) else
-                _jit("spec_mixed_nomem", _spec_mixed_plain,
-                     donate=(0,))),
+                _wjit("spec_mixed_nomem", _spec_mixed_plain,
+                      donate=(0,))),
         }
         self._lane_closures[cache_key] = closures
         return closures
 
     def _first_token(self, h_last):
         """Greedy token from the prefill's last hidden state [B,d]."""
-        logits = T.compute_logits(self.params, self.cfg, h_last)
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return _greedy_token(self.params, self.cfg, h_last)
+
+    def _weighted(self, fn, *, donate=(), static=(), shardings=None):
+        """jit `fn(params, gates, *args)` and bind this engine's
+        weights: argument indices (donate/static) and `shardings`
+        (in, out) name the args AFTER the weights."""
+        kw = {}
+        if static:
+            kw["static_argnums"] = tuple(i + 2 for i in static)
+        if donate:
+            kw["donate_argnums"] = tuple(i + 2 for i in donate)
+        if shardings is not None:
+            ins, outs = shardings
+            kw["in_shardings"] = tuple(self._weight_shardings) + ins
+            kw["out_shardings"] = outs
+        return _Weighted(self, jax.jit(fn, **kw))
 
     # ------------------------------------------------------------ state
 

@@ -15,8 +15,8 @@ def abstract_mesh(shape, axes):
     """Abstract mesh over DUPLICATED host devices — sharding METADATA
     only (specs, divisibility guards), never execution. Tests that need
     programs to actually SPMD-partition must go through a subprocess
-    with --xla_force_host_platform_device_count and
-    launch/mesh.make_cpu_mesh instead (tests/test_shard_serve.py)."""
+    with virtual CPU devices and launch/mesh.make_serving_mesh instead
+    (tests/test_shard_serve.py)."""
     n = int(np.prod(shape))
     devs = np.array(jax.devices() * n)[:n].reshape(shape)
     return Mesh(devs, axes)

@@ -1,6 +1,6 @@
 """Sharded serving end-to-end: runs repro.launch.shard_serve in a
-SUBPROCESS (it needs --xla_force_host_platform_device_count=8 before
-jax init, which must not leak into this test process) and asserts the
+SUBPROCESS (it asks for 8 virtual CPU devices before jax init, which
+must not leak into this test process) and asserts the
 tentpole contract on REAL SPMD execution over 8 virtual CPU devices:
 
   * token-identity: every request served through a mesh-sharded
