@@ -83,5 +83,6 @@ def capacity_loss_pallas(beta, M: float, *, block: int = 256,
         out_shape=jax.ShapeDtypeStruct((B * H, n_blk, 1, 1), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block, 1), jnp.float32)],
         interpret=interpret,
+        name="capacity_loss",
     )(lb)
     return jnp.sum(partial) / (B * H) / T

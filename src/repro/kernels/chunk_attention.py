@@ -232,6 +232,7 @@ def chunk_attention_pallas(q, k_c, v_c, cache_k, cache_v, cache_pos,
             pltpu.VMEM((q_block, D), jnp.float32),
         ],
         interpret=interpret,
+        name="chunk_attn",
     )(qh, ck, cv, cp, kh, vh, qp_c, qp_q)
 
     out = res[0][:, :C].reshape(B, Hq, C, D)

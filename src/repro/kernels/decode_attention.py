@@ -194,6 +194,7 @@ def decode_attention_pallas(q_t, k_cache, v_cache, pos, t, *, window=0,
             pltpu.VMEM((1, D), jnp.float32),
         ],
         interpret=interpret,
+        name="decode_attn",
     )(qh, kh, vh, ph, knh, vnh, t_arr)
     if not return_probs:
         return res[0].reshape(B, Hq, D)

@@ -139,6 +139,7 @@ def retention_attention_pallas(q, k, v, log_beta=None, *, causal=True,
             pltpu.VMEM((q_block, D), jnp.float32),
         ],
         interpret=interpret,
+        name="retention_attn",
     )(off, qh, kh, vh, lbh)
     out = out[:, :Tq].reshape(B, Hq, Tq, D)
     return jnp.moveaxis(out, 1, 2)

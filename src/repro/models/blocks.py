@@ -583,25 +583,29 @@ def apply_block_decode(p, g, cfg, kind, x_t, state, t, *, policy,
         # Alg. 1: attend over (cache ∪ provisional new token), THEN
         # evict-if-full — one pass over the old cache serves both the
         # attention read and the eviction blend (§Perf iteration 4)
-        if attn_impl == "pallas":
-            # lazy import: the pallas toolchain loads only when the
-            # serving path actually selects it (ops.py convention)
-            from repro.kernels import ops as kernel_ops
-            out, probs, p_new = kernel_ops.decode_attention(
-                q_t, cache["k"], cache["v"], cache["pos"], t,
-                window=window, new_kv=(k_t, v_t), return_probs=True,
-                impl="pallas")
-        else:
-            out, probs, p_new = decode_attend(q_t, cache, window=window,
-                                              t=t, new_kv=(k_t, v_t))
+        # the named scopes (decode_attn, kv_insert) label these ops in
+        # the compiled HLO and so in a profiler trace of the chip
+        with jax.named_scope("decode_attn"):
+            if attn_impl == "pallas":
+                # lazy import: the pallas toolchain loads only when the
+                # serving path actually selects it (ops.py convention)
+                from repro.kernels import ops as kernel_ops
+                out, probs, p_new = kernel_ops.decode_attention(
+                    q_t, cache["k"], cache["v"], cache["pos"], t,
+                    window=window, new_kv=(k_t, v_t), return_probs=True,
+                    impl="pallas")
+            else:
+                out, probs, p_new = decode_attend(
+                    q_t, cache, window=window, t=t, new_kv=(k_t, v_t))
         pkv = _probs_to_kv(probs, cfg)
-        cache = policy.decode_update(cache, pkv, active=active)
         inc = 1.0 if policy.name == "trimkv" else None
         aux_new = (_probs_to_kv(p_new[..., None], cfg)[..., 0]
                    if policy.needs_attn else None)
-        cache = cache_insert(cache, k_t, v_t, beta_t, t,
-                             policy.keep_scores, incoming_score=inc,
-                             incoming_aux=aux_new)
+        with jax.named_scope("kv_insert"):
+            cache = policy.decode_update(cache, pkv, active=active)
+            cache = cache_insert(cache, k_t, v_t, beta_t, t,
+                                 policy.keep_scores, incoming_score=inc,
+                                 incoming_aux=aux_new)
         x = x_t + dense_apply(p["attn"]["wo"],
                               out.reshape(x_t.shape[0], cfg.q_dim)
                               .astype(x_t.dtype))
@@ -1033,18 +1037,19 @@ def apply_block_prefill_chunk(p, g, cfg, kind, x, state, t0, *, policy,
         t_end = t0b + nvb - 1                                 # [B]
         q, k, v = _qkv(p["attn"], cfg, normed, positions)
         window = cfg.window if kind == "local" else 0
-        if attn_impl == "pallas":
-            # lazy import: the pallas toolchain loads only when the
-            # serving path actually selects it (ops.py convention).
-            # needs_attn=False policies discard probs_cache, so the
-            # kernel skips those outputs entirely
-            from repro.kernels import ops as kernel_ops
-            out, probs_cache = kernel_ops.chunk_attention(
-                q, k, v, cache, chunk_pos, window=window,
-                need_probs=policy.needs_attn, impl="pallas")
-        else:
-            out, probs_cache = _chunk_attend(q, k, v, cache, chunk_pos,
-                                             window)
+        with jax.named_scope("chunk_attn"):
+            if attn_impl == "pallas":
+                # lazy import: the pallas toolchain loads only when the
+                # serving path actually selects it (ops.py convention).
+                # needs_attn=False policies discard probs_cache, so the
+                # kernel skips those outputs entirely
+                from repro.kernels import ops as kernel_ops
+                out, probs_cache = kernel_ops.chunk_attention(
+                    q, k, v, cache, chunk_pos, window=window,
+                    need_probs=policy.needs_attn, impl="pallas")
+            else:
+                out, probs_cache = _chunk_attend(q, k, v, cache,
+                                                 chunk_pos, window)
         if g is not None and cfg.trimkv:
             beta_c = jnp.moveaxis(gates_lib.gate_beta(g, normed), 1, 2)
         else:
@@ -1064,9 +1069,10 @@ def apply_block_prefill_chunk(p, g, cfg, kind, x, state, t0, *, policy,
                                  (B, cfg.num_kv_heads, C))
         chunk_scores = policy.chunk_scores(pos_c=pos_c, beta_c=beta_c,
                                            aux_c=aux_c, k_c=k_c, t=t_end)
-        new_cache = cache_topm_merge(cache, k_c, v_c, beta_c, pos_c, aux_c,
-                                     t_end, policy.keep_scores,
-                                     chunk_scores)
+        with jax.named_scope("kv_merge"):
+            new_cache = cache_topm_merge(cache, k_c, v_c, beta_c, pos_c,
+                                         aux_c, t_end, policy.keep_scores,
+                                         chunk_scores)
         x = x + dense_apply(p["attn"]["wo"], out.reshape(B, C, cfg.q_dim))
         new_state = new_cache
         if kind == "cross":

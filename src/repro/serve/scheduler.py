@@ -111,6 +111,7 @@ the whole wave drains) — the baseline the serving benchmark
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Dict, Iterable, List, Optional, Tuple
 
@@ -126,6 +127,13 @@ from repro.serve.store import SnapshotStore, state_spec
 
 SCHED_POLICIES = ("fifo", "priority", "edf")
 SHED_POLICIES = ("reject", "evict")
+
+
+def _span(name: str):
+    """Decorator: the method's calls become `name` spans in a
+    jax.profiler trace (docs/serving.md §Tracing); nothing is recorded
+    while no profiler runs."""
+    return functools.partial(jax.profiler.annotate_function, name=name)
 
 
 def _chunk_prompt(prompt: np.ndarray, C: int):
@@ -349,6 +357,15 @@ class Scheduler:
         # were split into a mixed part + a pure-decode remainder (each
         # half is its own dispatch and counts in n_segments)
         self.n_segment_splits = 0
+        # device work of the segment dispatches (docs/serving.md
+        # §Tracing): scan steps the pure-decode programs ran (the
+        # power-of-two bucket, masked tail included), mixed steps, and
+        # the prompt rows their chunk sub-step ran (drain x lanes x C)
+        # against the rows that held prompt tokens
+        self.n_decode_steps = 0
+        self.n_mixed_steps = 0
+        self.n_chunk_rows = 0
+        self.n_chunk_rows_valid = 0
         # speculative-decode counters: verify rounds dispatched
         # (logical, drain-split aware — when spec is on,
         # n_verify_rounds == decode_segment * (n_segments -
@@ -993,6 +1010,7 @@ class Scheduler:
         return ([(rs, snap, next(lanes)) for rs, snap in resume],
                 [(rs, next(lanes)) for rs in fresh])
 
+    @_span("serve.admit")
     def _admit(self) -> int:
         """Phased admission (PR 3): fill free lanes from the queue —
         the whole admission batch (ragged prefill, first tokens, masked
@@ -1079,6 +1097,7 @@ class Scheduler:
                 self._seed_hist(lane, rs)
         return len(resume) + k
 
+    @_span("serve.admit")
     def _admit_interleaved(self) -> int:
         """Interleaved admission: assign requests to free lanes and
         chunk their prompts host-side; the prefill itself is threaded
@@ -1126,6 +1145,7 @@ class Scheduler:
 
     # ---------------------------------------------------------- decoding
 
+    @_span("serve.plan")
     def _build_prefill_schedule(self, n_steps: int):
         """Lay this segment's prompt chunks onto the [n_steps, B] grid:
         one chunk per prefilling lane per step, lanes visited in
@@ -1184,6 +1204,7 @@ class Scheduler:
                      for l in lanes}
         return chunks, nv, finish, new_keys, scheduled, install, drain
 
+    @_span("serve.mixed")
     def _dispatch_mixed(self, chunks, nv, finish, new_keys, scheduled,
                         install):
         """One mixed prefill/decode dispatch running the prebuilt
@@ -1196,6 +1217,9 @@ class Scheduler:
         re-running the encoder/vision projection."""
         self.eng.dispatch_count += 1
         self.n_segments += 1
+        self.n_mixed_steps += chunks.shape[0]
+        self.n_chunk_rows += chunks.size
+        self.n_chunk_rows_valid += int(nv.sum())
         spec = self.spec_k > 0
         args = (self.state, self.tok, self.keys, jnp.asarray(self.active),
                 jnp.asarray(self.n_emitted), jnp.asarray(self.max_new),
@@ -1213,22 +1237,15 @@ class Scheduler:
             mixed_fn = self._spec_mixed if spec else self._mixed
         if spec:
             self.n_verify_rounds += int(chunks.shape[0])
-            (self.state, self.tok, self.keys, active_d, n_emitted_d,
-             ids, emitted, ok, hist_d, a_tok, a_rnd) = mixed_fn(*args)
-            self.hist = np.array(hist_d)
-            self._account_spec(np.asarray(a_tok), np.asarray(a_rnd))
-        else:
-            (self.state, self.tok, self.keys, active_d, n_emitted_d,
-             ids, emitted, ok) = mixed_fn(*args)
+        outs = mixed_fn(*args)
         for lane, n in scheduled.items():
             pf = self.lane_prefill[lane]
             pf.next_chunk += n
             if pf.done:
                 self.lane_prefill[lane] = None       # decoding now
-        self.active = np.array(active_d)
-        self.n_emitted = np.array(n_emitted_d)
-        return np.asarray(ids), np.asarray(emitted), np.array(ok)
+        return self._fetch(outs)
 
+    @_span("serve.decode")
     def _dispatch_decode(self, n_steps: int):
         """One pure-decode dispatch of n_steps steps (a full segment,
         or the drained remainder of a split interleaved segment).
@@ -1245,38 +1262,49 @@ class Scheduler:
         self.decode_bucket_lengths.add(bucket)
         self.eng.dispatch_count += 1
         self.n_segments += 1
+        self.n_decode_steps += bucket
         if self.spec_k > 0:
             # speculative segment: `bucket` static VERIFY ROUNDS (same
             # pow2 contract, round units), n_steps logical; each round
             # commits 1..spec_k+1 tokens per live lane, so the returned
             # grids carry n_steps * (spec_k + 1) token columns
             self.n_verify_rounds += n_steps
-            (self.state, self.tok, self.keys, active_d, n_emitted_d,
-             ids, emitted, ok, hist_d, a_tok, a_rnd) = \
-                self._spec_segment_fn(
-                    self.state, self.tok, self.keys,
-                    jnp.asarray(self.active),
-                    jnp.asarray(self.n_emitted),
-                    jnp.asarray(self.max_new), jnp.asarray(self.eos),
-                    jnp.asarray(self.hist), bucket, np.int32(n_steps))
-            self.hist = np.array(hist_d)
-            self._account_spec(np.asarray(a_tok), np.asarray(a_rnd))
+            outs = self._spec_segment_fn(
+                self.state, self.tok, self.keys, jnp.asarray(self.active),
+                jnp.asarray(self.n_emitted), jnp.asarray(self.max_new),
+                jnp.asarray(self.eos), jnp.asarray(self.hist), bucket,
+                np.int32(n_steps))
+            n_cols = n_steps * (self.spec_k + 1)
+        else:
+            outs = self._segment(
+                self.state, self.tok, self.keys, jnp.asarray(self.active),
+                jnp.asarray(self.n_emitted), jnp.asarray(self.max_new),
+                jnp.asarray(self.eos), bucket, np.int32(n_steps))
+            n_cols = n_steps
+        ids, emitted, ok = self._fetch(outs)
+        # masked bucket-tail steps emit nothing; slice to logical length
+        return ids[:, :n_cols], emitted[:, :n_cols], ok
+
+    def _fetch(self, outs):
+        """Adopt one segment dispatch's outputs: the lane state stays
+        on the device; the lane bookkeeping, the token grids, the health
+        flags and (speculative programs) the drafter history and
+        acceptance counters come to the host. The first conversion
+        blocks until the program has run: the serve.wait span."""
+        (self.state, self.tok, self.keys, active_d, n_emitted_d, ids,
+         emitted, ok, *spec) = outs
+        with jax.profiler.TraceAnnotation("serve.wait"):
+            # np.array (copy): asarray views of device buffers are
+            # read-only, and the scheduler writes these
             self.active = np.array(active_d)
             self.n_emitted = np.array(n_emitted_d)
-            n_cols = n_steps * (self.spec_k + 1)
-            return (np.asarray(ids)[:, :n_cols],
-                    np.asarray(emitted)[:, :n_cols], np.array(ok))
-        (self.state, self.tok, self.keys, active_d, n_emitted_d, ids,
-         emitted, ok) = self._segment(
-            self.state, self.tok, self.keys, jnp.asarray(self.active),
-            jnp.asarray(self.n_emitted), jnp.asarray(self.max_new),
-            jnp.asarray(self.eos), bucket, np.int32(n_steps))
-        # np.array (copy): asarray views of device buffers are read-only
-        self.active = np.array(active_d)
-        self.n_emitted = np.array(n_emitted_d)
-        # masked bucket-tail steps emit nothing; slice to logical length
-        return (np.asarray(ids)[:, :n_steps],
-                np.asarray(emitted)[:, :n_steps], np.array(ok))
+            ids, emitted, ok = (np.asarray(ids), np.asarray(emitted),
+                                np.array(ok))
+            spec = [np.array(x) for x in spec]
+        if spec:
+            self.hist, a_tok, a_rnd = spec
+            self._account_spec(a_tok, a_rnd)
+        return ids, emitted, ok
 
     def _account_spec(self, a_tok: np.ndarray, a_rnd: np.ndarray):
         """Fold one spec dispatch's per-lane acceptance counters
@@ -1367,6 +1395,14 @@ class Scheduler:
                 ok = ok & ok2
         else:
             ids, emitted, ok = self._dispatch_decode(n_steps)
+        return self._retire(ids, emitted, ok, t_seg0)
+
+    @_span("serve.retire")
+    def _retire(self, ids, emitted, ok, t_seg0) -> List[RequestState]:
+        """The tail of a segment: harvest each lane's emissions,
+        quarantine lanes whose health flag tripped, capture prefix
+        slabs, reset retired lanes and take periodic checkpoints.
+        Returns the requests that finished."""
         bad = [l for l in range(self.n_lanes)
                if not ok[l] and self.lane_req[l] is not None]
         finished, retired_lanes, now = [], [], self._now()
@@ -1459,6 +1495,10 @@ class Scheduler:
             "n_prefill_rounds": self.n_prefill_rounds,
             "n_segments": self.n_segments,
             "n_segment_splits": self.n_segment_splits,
+            "n_decode_steps": self.n_decode_steps,
+            "n_mixed_steps": self.n_mixed_steps,
+            "n_chunk_rows": self.n_chunk_rows,
+            "n_chunk_rows_valid": self.n_chunk_rows_valid,
             "n_resets": self.n_resets,
             "n_preempted": self.n_preempted,
             "n_swaps": self.n_swaps,

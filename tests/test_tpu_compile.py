@@ -11,6 +11,7 @@ that runs this file loads libtpu. Everything compiles in this process.
 """
 import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -62,6 +63,12 @@ def _compile(fn, *args):
     return jax.jit(fn).lower(*args).compile()
 
 
+def _kernel_named(text, name):
+    """The compiled HLO holds a Pallas kernel whose custom call bears
+    `name` (the pallas_call's name=, which a profiler trace shows)."""
+    return re.search(rf"%{name}(\.\d+)? = [^\n]*custom-call\(", text)
+
+
 def _dims():
     cfg = get_config(ARCH)
     return cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
@@ -83,6 +90,7 @@ def test_decode_kernel_compiles(one_chip):
 
     compiled = _compile(f, *args)
     assert "tpu_custom_call" in compiled.as_text()
+    assert _kernel_named(compiled.as_text(), "decode_attn")
     out, probs, p_new = jax.eval_shape(f, *args)
     assert probs.shape == (LANES, hq, M) and p_new.shape == (LANES, hq)
 
@@ -103,7 +111,9 @@ def test_chunk_kernel_compiles(one_chip, need_probs):
                                       need_probs=need_probs,
                                       interpret=False)
 
-    assert "tpu_custom_call" in _compile(f, *args).as_text()
+    text = _compile(f, *args).as_text()
+    assert "tpu_custom_call" in text
+    assert _kernel_named(text, "chunk_attn")
 
 
 def test_retention_kernel_compiles(one_chip):
@@ -117,7 +127,9 @@ def test_retention_kernel_compiles(one_chip):
     def f(q, k, v, lb):
         return retention_attention_pallas(q, k, v, lb, interpret=False)
 
-    assert "tpu_custom_call" in _compile(f, *args).as_text()
+    text = _compile(f, *args).as_text()
+    assert "tpu_custom_call" in text
+    assert _kernel_named(text, "retention_attn")
 
 
 def test_capacity_loss_kernel_compiles(one_chip):
@@ -128,15 +140,14 @@ def test_capacity_loss_kernel_compiles(one_chip):
                                                        interpret=False),
                         beta)
     assert "tpu_custom_call" in compiled.as_text()
+    assert _kernel_named(compiled.as_text(), "capacity_loss")
 
 
-def test_segment_program_takes_weights_as_arguments(one_chip, monkeypatch):
+@pytest.fixture(scope="module")
+def segment(one_chip):
     """One decode-segment program of the lane scheduler at full width
-    and 2 layers, from eval_shape shapes: the Pallas kernels are in it,
-    the weights are counted as arguments, and the lowered text stays
-    small — a weight compiled in as a constant would add its bytes."""
-    # off the chip ops.py picks interpret mode; steer it to the chip's
-    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    and 2 layers, from eval_shape shapes, compiled for the described
+    chip: (lowered, compiled, bytes of the weights)."""
     cfg = dataclasses.replace(get_config(ARCH), num_layers=2)
     on_chip = lambda tree: jax.tree.map(
         lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
@@ -147,7 +158,6 @@ def test_segment_program_takes_weights_as_arguments(one_chip, monkeypatch):
                                    kg))
     weight_bytes = sum(s.size * s.dtype.itemsize
                        for s in jax.tree.leaves((params, gates)))
-    assert weight_bytes > 1.5e9        # full width: ~1.6 GB of embeddings
     eng = build_engine(cfg, params, gates, attn_impl="pallas",
                        policy="trimkv", budget=512, prefill_chunk=C,
                        decode_segment=16)
@@ -158,9 +168,30 @@ def test_segment_program_takes_weights_as_arguments(one_chip, monkeypatch):
         ((LANES,), jnp.int32), ((LANES,), jnp.int32), ((LANES,), jnp.int32))
     (keys,) = _specs(one_chip, ((LANES, 2), jnp.uint32))
     (n_real,) = _specs(one_chip, ((), jnp.int32))
-    lowered = eng.lane_closures(True)["segment"].lower(
-        state, tok, keys, active, n_emitted, max_new, eos, 16, n_real)
+    # off the chip ops.py picks interpret mode; steer it to the chip's
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ops, "_interpret", lambda: False)
+        lowered = eng.lane_closures(True)["segment"].lower(
+            state, tok, keys, active, n_emitted, max_new, eos, 16, n_real)
+        return lowered, lowered.compile(), weight_bytes
+
+
+def test_segment_program_takes_weights_as_arguments(segment):
+    """The Pallas kernels are in the segment program, the weights are
+    counted as arguments, and the lowered text stays small — a weight
+    compiled in as a constant would add its bytes."""
+    lowered, compiled, weight_bytes = segment
+    assert weight_bytes > 1.5e9        # full width: ~1.6 GB of embeddings
     assert len(lowered.as_text()) < 10 * 2 ** 20
-    compiled = lowered.compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().argument_size_in_bytes >= weight_bytes
+
+
+def test_segment_program_names_its_kernel_and_cache_update(segment):
+    """The names a profiler trace of the chip shows: the decode kernel's
+    custom call is decode_attn, and the decode attention and the cache
+    update carry their named scopes in the op_name metadata."""
+    text = segment[1].as_text()
+    assert _kernel_named(text, "decode_attn")
+    for scope in ("decode_attn", "kv_insert"):
+        assert re.search(rf'op_name="[^"]*/{scope}/', text), scope
