@@ -272,6 +272,11 @@ def run_check_hlo(eng, n_lanes):
                      np.int32(n))),
         "admit": (cl["admit"],
                   (state, tok, keys, chunks, nv, keys, bmask)),
+        # interleaved admission: on a lane-sharded mesh the chunk
+        # sub-step stays lane-aligned, so this too stays shard-local
+        "mixed": (cl["mixed"],
+                  (state, tok, keys, bmask, i32, i32, i32, chunks, nv,
+                   nv > 0, keys)),
         "resume": (cl["resume"],
                    (state, tok, keys, state, tok, keys, bmask)),
         "extract": (cl["extract"], (state, tok, keys)),

@@ -984,7 +984,11 @@ def _chunk_attend(q, k_c, v_c, cache, chunk_pos, window):
     keys_r = jnp.repeat(keys, group, axis=1)
     vals_r = jnp.repeat(vals, group, axis=1)
     pos_r = jnp.repeat(pos, group, axis=1)                   # [B,Hq,M+C]
-    s = jnp.einsum("bchd,bhnd->bhcn", q.astype(jnp.float32), keys_r)
+    # queries head-major before the dot: the "bchd" operand made the
+    # CPU backend sum each score in an order that depended on B, so a
+    # lane's scores were not bit-identical between batch 1 and batch B
+    s = jnp.einsum("bhcd,bhnd->bhcn",
+                   jnp.moveaxis(q, 1, 2).astype(jnp.float32), keys_r)
     s = s / np.sqrt(D)
     qpos = cp2[:, None, :, None]
     dist = qpos - pos_r[:, :, None, :]

@@ -367,6 +367,136 @@ def _prefill_chunk_step(params, gate_params, cfg, tokens, state, policy,
     return new_state, h_last
 
 
+def _lane_row(leaf, at):
+    """Rows of `leaf` at index prefix `at` (int32 scalars, the lane
+    index last) as a batch-1 block: [R, B, ...] at (r, lane) or
+    [B, ...] at (lane,) -> [1, ...]."""
+    k = len(at)
+    row = jax.lax.dynamic_slice(leaf, at + (0,) * (leaf.ndim - k),
+                                (1,) * k + leaf.shape[k:])
+    return row.reshape((1,) + leaf.shape[k:])
+
+
+def _put_lane_row(leaf, row, at):
+    """Inverse of _lane_row: write the batch-1 block back in place."""
+    k = len(at)
+    return jax.lax.dynamic_update_slice(
+        leaf, row.reshape((1,) * k + leaf.shape[k:]),
+        at + (0,) * (leaf.ndim - k))
+
+
+def _prefill_chunk_lane(params, gate_params, cfg, tokens, state, lane,
+                        n_valid, policy, serve_cfg):
+    """_prefill_chunk_step for ONE lane of the B-lane state, in place:
+    tokens [1,C], n_valid [1] (> 0), lane a traced int32 index. Each
+    layer slices the lane's rows of its block state, runs the chunk at
+    batch 1 and writes the rows back (dynamic_update_slice on the
+    carried stack), so no other lane is read or written and no
+    whole-stack copy of the state is made. Returns (state, h_last
+    [1,d])."""
+    unit, U, R, tail = _unit_and_counts(cfg)
+    h = jnp.take(params["embed"], tokens, axis=0)
+    C = tokens.shape[1]
+    t0 = jax.lax.dynamic_slice_in_dim(state["t"], lane, 1)
+    attn_impl = getattr(serve_cfg, "attn_impl", "xla")
+
+    def block(p, g, kind, h, st, at):
+        h, ns, _ = blocks.apply_block_prefill_chunk(
+            p, g, cfg, kind, h, jax.tree.map(lambda a: _lane_row(a, at), st),
+            t0, policy=policy, obs_window=serve_cfg.obs_window,
+            n_valid=n_valid, attn_impl=attn_impl)
+        return h, jax.tree.map(lambda a, n: _put_lane_row(a, n, at), st, ns)
+
+    def unit_body(carry, xs):
+        h, layers = carry
+        up, ug, r = xs
+        new = []
+        for i, kind in enumerate(unit):
+            g = ug[i] if ug is not None else None
+            h, ns = block(up[i], g, kind, h, layers[i], (r, lane))
+            new.append(ns)
+        return (h, tuple(new)), None
+
+    new_state = {"t": jax.lax.dynamic_update_slice_in_dim(
+        state["t"], t0 + n_valid, lane, 0)}
+    if R > 0:
+        glayers = (gate_params or {}).get("layers")
+        (h, new_state["layers"]), _ = jax.lax.scan(
+            unit_body, (h, state["layers"]),
+            (params["layers"], glayers, jnp.arange(R, dtype=jnp.int32)),
+            unroll=R if cfg.unroll_layers else 1)
+    else:
+        new_state["layers"] = None
+    new_tail = []
+    for i, kind in enumerate(tail):
+        g = (gate_params or {}).get("tail", (None,) * len(tail))[i]
+        h, ns = block(params["tail"][i], g, kind, h, state["tail"][i],
+                      (lane,))
+        new_tail.append(ns)
+    new_state["tail"] = tuple(new_tail)
+    h = rmsnorm_apply(params["final_norm"], h, cfg.norm_eps)
+    ix = jnp.clip(n_valid - 1, 0, C - 1).astype(jnp.int32)
+    return new_state, jnp.take_along_axis(h, ix[:, None, None], axis=1)[:, 0]
+
+
+def _chunk_substep(params, gate_params, cfg, tokens, state, policy,
+                   serve_cfg, n_valid, compact):
+    """The chunk (prefill) sub-step of the mixed programs: tokens
+    [B,C], n_valid [B] (0 = no chunk for that lane this step). Returns
+    (state, h_last [B,d]); h_last rows of lanes without a chunk are
+    meaningless.
+
+    compact=False runs _prefill_chunk_step over the whole lane-aligned
+    [B,C] grid (zero-valid rows computed, then frozen). compact=True
+    runs only the lanes holding a chunk: a fori_loop with a traced trip
+    count (the number of such lanes) runs one lane's [1,C] chunk per
+    pass (_prefill_chunk_lane), so every shape is fixed whatever the
+    admission count, the other lanes are never computed and stay
+    bit-identical, and each pass writes its h_last row into the [B,d]
+    buffer. A one-lane [1,C] chunk at C in the hundreds already fills
+    the matrix unit, so one lane per pass loses little to batching.
+    Lane-sharded meshes take compact=False: slicing a lane out of a
+    sharded lane axis would put collectives in the hot loop."""
+    if not compact:
+        return _prefill_chunk_step(params, gate_params, cfg, tokens, state,
+                                   policy, serve_cfg, n_valid=n_valid)
+    B = tokens.shape[0]
+    has = n_valid > 0
+    lanes = jnp.nonzero(has, size=B, fill_value=0)[0].astype(jnp.int32)
+
+    def one(i, carry):
+        state, h_last = carry
+        lane = lanes[i]
+        state, h = _prefill_chunk_lane(
+            params, gate_params, cfg,
+            jax.lax.dynamic_slice_in_dim(tokens, lane, 1), state, lane,
+            jax.lax.dynamic_slice_in_dim(n_valid, lane, 1), policy,
+            serve_cfg)
+        return state, jax.lax.dynamic_update_slice_in_dim(h_last, h, lane,
+                                                           0)
+
+    h0 = jnp.zeros((B, cfg.d_model), params["embed"].dtype)
+    return jax.lax.fori_loop(0, jnp.sum(has.astype(jnp.int32)), one,
+                             (state, h0))
+
+
+def _finish_first(params, cfg, fin, h_last):
+    """The prefill -> decode transition's greedy first token and logit
+    health for lanes finishing their prompt (fin [B] bool), from their
+    last prompt token's hidden h_last [B,d]. The full-vocab projection
+    only runs on steps where some lane finishes."""
+    def first_and_health(h):
+        lg = compute_logits(params, cfg, h)
+        return (jnp.argmax(lg, axis=-1).astype(jnp.int32),
+                jnp.all(jnp.isfinite(lg), axis=-1))
+
+    return jax.lax.cond(
+        jnp.any(fin), first_and_health,
+        lambda h: (jnp.zeros((h.shape[0],), jnp.int32),
+                   jnp.ones((h.shape[0],), bool)),
+        h_last)
+
+
 def install_memory(params, cfg, state, memory, mem_len, lanes_mask=None):
     """Write cross-attention memory K/V into every cross layer's state:
     xk/xv from make_memory_kv(memory) and the per-lane valid length
@@ -721,7 +851,7 @@ def mixed_step_loop(params, gate_params, cfg, state, tok, keys, active,
                     n_emitted, max_new, eos_id, chunks, chunk_valid,
                     finish, new_keys, policy, serve_cfg, *, greedy=True,
                     temperature=0.0, attn_impl="xla", mem_inputs=None,
-                    mem_install=None):
+                    mem_install=None, compact=True):
     """Interleaved prefill/decode segment (the PR-4 SLO hot path): ONE
     lax.scan whose every step advances the active DECODE lanes by one
     token AND feeds at most one prefill chunk per ADMITTING lane — so a
@@ -729,16 +859,20 @@ def mixed_step_loop(params, gate_params, cfg, state, tok, keys, active,
     (head-of-line blocking), and admission costs ZERO extra dispatches:
     it rides inside the segment program.
 
-    Per step j the body runs two complementary masked sub-steps over the
-    same B-lane state:
+    Per step j the body runs two complementary sub-steps over the same
+    B-lane state:
 
       1. decode_step with `active` as the mask (exactly the
          decode_segment_loop body: emit carried token, feed it, sample
          the next per-lane) — prefilling/empty lanes are frozen
          bit-identically;
-      2. _prefill_chunk_step on chunks[j] with per-lane chunk_valid[j]
-         (a lane's next prompt chunk, or 0 = frozen row) — decode lanes
-         have zero-valid rows and are frozen bit-identically.
+      2. the chunk sub-step (_chunk_substep) on chunks[j] with per-lane
+         chunk_valid[j] (a lane's next prompt chunk, or 0 = no chunk):
+         with compact=True only the lanes holding a chunk are computed,
+         one [1,C] lane per pass, and every other lane is left in
+         place; with compact=False (lane-sharded meshes) the whole
+         [B,C] grid runs and zero-valid rows are frozen
+         bit-identically.
 
     A lane is in at most ONE mode per step (the scheduler guarantees
     active[lane] => chunk_valid[j, lane] == 0), so the combined effect
@@ -796,27 +930,16 @@ def mixed_step_loop(params, gate_params, cfg, state, tok, keys, active,
                        (n_emitted >= max_new))
         new_tok = jnp.where(emit, nxt, tok)
         dec_active = active & ~done
-        # --- prefill sub-step (zero-valid rows frozen bit-identically)
-        state, h_last = _prefill_chunk_step(params, gate_params, cfg,
-                                            ctoks, state, policy,
-                                            serve_cfg, n_valid=nv)
+        # --- chunk sub-step (lanes without a chunk left bit-identical)
+        state, h_last = _chunk_substep(params, gate_params, cfg, ctoks,
+                                       state, policy, serve_cfg, nv,
+                                       compact)
         # --- transition: finishing lanes take their greedy first token
         # (one-shot parity: Engine.generate argmaxes the prefill
         # logits even under temperature sampling) and their request's
         # RNG key AFTER this step's split, so their first sampled draw
-        # consumes split(seed_key) exactly like a fresh decode_loop.
-        # The full-vocab projection only pays on steps where some lane
-        # actually finishes (at most one step per lane per prompt)
-        def _first_and_health(h):
-            lg = compute_logits(params, cfg, h)
-            return (jnp.argmax(lg, axis=-1).astype(jnp.int32),
-                    jnp.all(jnp.isfinite(lg), axis=-1))
-
-        first, fin_ok = jax.lax.cond(
-            jnp.any(fin), _first_and_health,
-            lambda h: (jnp.zeros((h.shape[0],), jnp.int32),
-                       jnp.ones((h.shape[0],), bool)),
-            h_last)
+        # consumes split(seed_key) exactly like a fresh decode_loop
+        first, fin_ok = _finish_first(params, cfg, fin, h_last)
         ok = ok & (~fin | fin_ok)
         new_tok = jnp.where(fin, first, new_tok)
         keys = jnp.where(fin[:, None], new_keys, keys)
@@ -1083,7 +1206,7 @@ def spec_mixed_step_loop(params, gate_params, cfg, state, tok, keys,
                          chunks, chunk_valid, finish, new_keys, policy,
                          serve_cfg, *, spec_k, attn_impl="xla",
                          mem_inputs=None, mem_install=None,
-                         draft_fn=None):
+                         draft_fn=None, compact=True):
     """Speculative counterpart of mixed_step_loop: per scan step the
     decode lanes run one verify_round (1..spec_k+1 tokens each) while
     admitting lanes consume one prefill chunk; a lane finishing its
@@ -1091,8 +1214,9 @@ def spec_mixed_step_loop(params, gate_params, cfg, state, tok, keys,
     history EMPTY-handed — hist rows are seeded host-side at admission
     with the prompt tail, and the first carry token is exactly the
     prefill argmax, so no in-scan history write is needed at the
-    transition. Greedy-only. Returns the spec_decode_segment_loop tuple
-    (ids/emitted are [B, n_steps*(spec_k+1)])."""
+    transition. Greedy-only; compact as in mixed_step_loop. Returns the
+    spec_decode_segment_loop tuple (ids/emitted are
+    [B, n_steps*(spec_k+1)])."""
     if mem_inputs is not None:
         memory, mem_len = _memory_from_inputs(params, cfg, mem_inputs)
         state = install_memory(params, cfg, state, memory, mem_len,
@@ -1110,21 +1234,11 @@ def spec_mixed_step_loop(params, gate_params, cfg, state, tok, keys,
         ok = ok & (~active | r_ok)
         a_tok = a_tok + nc
         a_rnd = a_rnd + active.astype(jnp.int32)
-        # --- prefill sub-step + transition (mirrors mixed_step_loop)
-        state, h_last = _prefill_chunk_step(params, gate_params, cfg,
-                                            ctoks, state, policy,
-                                            serve_cfg, n_valid=nv)
-
-        def _first_and_health(h):
-            lg = compute_logits(params, cfg, h)
-            return (jnp.argmax(lg, axis=-1).astype(jnp.int32),
-                    jnp.all(jnp.isfinite(lg), axis=-1))
-
-        first, fin_ok = jax.lax.cond(
-            jnp.any(fin), _first_and_health,
-            lambda h: (jnp.zeros((h.shape[0],), jnp.int32),
-                       jnp.ones((h.shape[0],), bool)),
-            h_last)
+        # --- chunk sub-step + transition (mirrors mixed_step_loop)
+        state, h_last = _chunk_substep(params, gate_params, cfg, ctoks,
+                                       state, policy, serve_cfg, nv,
+                                       compact)
+        first, fin_ok = _finish_first(params, cfg, fin, h_last)
         ok = ok & (~fin | fin_ok)
         tok = jnp.where(fin, first, tok)
         keys = jnp.where(fin[:, None], new_keys, keys)

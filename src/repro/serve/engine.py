@@ -197,6 +197,14 @@ class Engine:
             return cfg.source_len, cfg.d_model
         return None
 
+    @property
+    def mixed_compact(self) -> bool:
+        """Whether the mixed programs run their chunk sub-step only on
+        the lanes holding a chunk (T._chunk_substep): everywhere except
+        where the mesh shards the lane axis, since slicing one lane out
+        of a sharded axis would add collectives to the hot loop."""
+        return not shard_rules.lanes_sharded(self.mesh)
+
     def lane_closures(self, greedy: bool, n_lanes: Optional[int] = None):
         """Jitted continuous-batching closures (serve.scheduler), built
         lazily and CACHED PER ENGINE so every Scheduler constructed on
@@ -227,6 +235,7 @@ class Engine:
         cfg = self.cfg
         serve, policy, impl = self.serve, self.policy, self.serve.attn_impl
         mem_key = self.mem_key
+        compact = self.mixed_compact
 
         def _admit_core(params, gates, state, tok, keys, chunks, n_valid,
                         new_keys, lane_mask, extra):
@@ -275,7 +284,8 @@ class Engine:
                 max_new, eos, chunks, chunk_valid, finish, new_keys,
                 policy, serve, greedy=greedy,
                 temperature=serve.temperature, attn_impl=impl,
-                mem_inputs=mem_inputs, mem_install=mem_install)
+                mem_inputs=mem_inputs, mem_install=mem_install,
+                compact=compact)
 
         def _mixed_plain(params, gates, state, tok, keys, active,
                          n_emitted, max_new, eos, chunks, chunk_valid,
@@ -373,7 +383,7 @@ class Engine:
                 max_new, eos, hist, chunks, chunk_valid, finish,
                 new_keys, policy, serve, spec_k=serve.spec_k,
                 attn_impl=impl, mem_inputs=mem_inputs,
-                mem_install=mem_install)
+                mem_install=mem_install, compact=compact)
 
         def _spec_mixed_plain(params, gates, state, tok, keys, active,
                               n_emitted, max_new, eos, hist, chunks,

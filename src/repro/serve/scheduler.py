@@ -360,8 +360,9 @@ class Scheduler:
         # device work of the segment dispatches (docs/serving.md
         # §Tracing): scan steps the pure-decode programs ran (the
         # power-of-two bucket, masked tail included), mixed steps, and
-        # the prompt rows their chunk sub-step ran (drain x lanes x C)
-        # against the rows that held prompt tokens
+        # the prompt rows their chunk sub-step ran (C per (step, lane)
+        # holding a chunk; drain x lanes x C where lanes are sharded and
+        # the whole grid runs) against the rows that held prompt tokens
         self.n_decode_steps = 0
         self.n_mixed_steps = 0
         self.n_chunk_rows = 0
@@ -1218,7 +1219,8 @@ class Scheduler:
         self.eng.dispatch_count += 1
         self.n_segments += 1
         self.n_mixed_steps += chunks.shape[0]
-        self.n_chunk_rows += chunks.size
+        self.n_chunk_rows += (int((nv > 0).sum()) * chunks.shape[2]
+                              if self.eng.mixed_compact else chunks.size)
         self.n_chunk_rows_valid += int(nv.sum())
         spec = self.spec_k > 0
         args = (self.state, self.tok, self.keys, jnp.asarray(self.active),

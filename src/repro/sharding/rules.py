@@ -46,6 +46,13 @@ def fsdp_axes(mesh: Mesh):
     return names if names else None
 
 
+def lanes_sharded(mesh) -> bool:
+    """True when serving lanes shard over the data axes of `mesh`
+    (("pod","data") of size > 1); False with no mesh or a model-only
+    mesh."""
+    return mesh is not None and _axis_size(mesh, fsdp_axes(mesh)) > 1
+
+
 def _guard(mesh: Mesh, dim: int, axes):
     """Return `axes` if dim divides evenly over them, else None."""
     if axes is None:

@@ -13,8 +13,8 @@ tentpole contract on REAL SPMD execution over 8 virtual CPU devices:
   * speculative decoding's exact-replay rollback survives sharding;
   * the exact dispatch-count formula is unchanged (asserted inside the
     driver per case);
-  * the hot-loop programs (admit / segment / resume / extract / reset)
-    compile with ZERO cross-shard resharding collectives on the
+  * the hot-loop programs (admit / segment / mixed / resume / extract
+    / reset) compile with ZERO cross-shard resharding collectives on the
     lane-parallel mesh — the shard-local admission contract checked on
     the optimized HLO, not trusted from the source.
 
@@ -77,14 +77,15 @@ def test_sharded_parity_head_parallel_mesh():
 
 @pytest.mark.slow
 def test_hot_loop_hlo_has_no_resharding_collectives():
-    """Lane-parallel mesh: the compiled admit / segment / resume /
-    extract / reset programs must contain no all-gather / all-to-all /
-    collective-permute (lane-aligned packing + mask-select installs
-    keep every dispatch shard-local on the lane axis)."""
+    """Lane-parallel mesh: the compiled admit / segment / mixed /
+    resume / extract / reset programs must contain no all-gather /
+    all-to-all / collective-permute (lane-aligned packing, mask-select
+    installs and the lane-aligned chunk sub-step keep every dispatch
+    shard-local on the lane axis)."""
     out = _json(_run(["--devices", "8", "--meshes", "8x1",
                       "--check-hlo"]))
     assert out["ok"]
-    assert set(out["programs"]) == {"segment", "admit", "resume",
-                                    "extract", "reset"}
+    assert set(out["programs"]) == {"segment", "admit", "mixed",
+                                    "resume", "extract", "reset"}
     for prog, found in out["resharding_collectives"].items():
         assert not found, (prog, found)

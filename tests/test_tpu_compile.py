@@ -15,6 +15,7 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -144,10 +145,11 @@ def test_capacity_loss_kernel_compiles(one_chip):
 
 
 @pytest.fixture(scope="module")
-def segment(one_chip):
-    """One decode-segment program of the lane scheduler at full width
-    and 2 layers, from eval_shape shapes, compiled for the described
-    chip: (lowered, compiled, bytes of the weights)."""
+def full_width(one_chip):
+    """The lane scheduler's engine at full width and 2 layers, from
+    eval_shape shapes placed on the described chip, with the lane
+    state and per-lane operand specs: (engine, state, tok, keys,
+    active, n_emitted, max_new, eos, bytes of the weights)."""
     cfg = dataclasses.replace(get_config(ARCH), num_layers=2)
     on_chip = lambda tree: jax.tree.map(
         lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
@@ -167,13 +169,22 @@ def segment(one_chip):
         one_chip, ((LANES,), jnp.int32), ((LANES,), jnp.bool_),
         ((LANES,), jnp.int32), ((LANES,), jnp.int32), ((LANES,), jnp.int32))
     (keys,) = _specs(one_chip, ((LANES, 2), jnp.uint32))
+    return (eng, state, tok, keys, active, n_emitted, max_new, eos,
+            weight_bytes)
+
+
+@pytest.fixture(scope="module")
+def segment(one_chip, full_width):
+    """One decode-segment program compiled for the described chip:
+    (lowered, compiled, bytes of the weights)."""
+    eng, state, tok, keys, active, n_emitted, max_new, eos, wb = full_width
     (n_real,) = _specs(one_chip, ((), jnp.int32))
     # off the chip ops.py picks interpret mode; steer it to the chip's
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(ops, "_interpret", lambda: False)
         lowered = eng.lane_closures(True)["segment"].lower(
             state, tok, keys, active, n_emitted, max_new, eos, 16, n_real)
-        return lowered, lowered.compile(), weight_bytes
+        return lowered, lowered.compile(), wb
 
 
 def test_segment_program_takes_weights_as_arguments(segment):
@@ -195,3 +206,57 @@ def test_segment_program_names_its_kernel_and_cache_update(segment):
     assert _kernel_named(text, "decode_attn")
     for scope in ("decode_attn", "kv_insert"):
         assert re.search(rf'op_name="[^"]*/{scope}/', text), scope
+
+
+def test_mixed_program_runs_the_chunk_one_lane_at_a_time(one_chip,
+                                                         full_width):
+    """The interleaved mixed program of an unsharded engine compiles
+    for the chip with its chunk sub-step compacted: the chunk kernel
+    runs on one lane's [1, C] chunk per pass, not on the [LANES, C]
+    grid."""
+    eng, state, tok, keys, active, n_emitted, max_new, eos, _ = full_width
+    chunks, nv, fin = _specs(one_chip, ((1, LANES, C), jnp.int32),
+                             ((1, LANES), jnp.int32),
+                             ((1, LANES), jnp.bool_))
+    assert eng.mixed_compact
+    cl = eng.lane_closures(True)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ops, "_interpret", lambda: False)
+        text = cl["mixed"].lower(state, tok, keys, active, n_emitted,
+                                 max_new, eos, chunks, nv, fin,
+                                 keys).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert _kernel_named(text, "decode_attn")
+    hq, _, d = _dims()
+    outs = re.findall(r"%chunk_attn(?:\.\d+)? = (\S+?)\{[^\n]*custom-call\(",
+                      text)
+    # the kernel's output rows are (lanes x heads, C, head_dim): one lane
+    assert outs and all(o == f"bf16[{hq},{C},{d}]" for o in outs), outs
+
+
+def test_mixed_program_is_one_executable_for_any_admission_count():
+    """No shape of the compacted mixed program depends on how many
+    lanes admit: one-lane and three-lane admissions run one executable
+    (on the CPU, a tiny engine)."""
+    cfg = dataclasses.replace(
+        get_config(ARCH), num_layers=2, d_model=64, d_ff=128, num_heads=4,
+        num_kv_heads=2, head_dim=16, vocab_size=64)
+    eng = build_engine(cfg, T.init_params(jax.random.PRNGKey(0), cfg),
+                       T.init_gate_params(jax.random.PRNGKey(1), cfg),
+                       budget=16, prefill_chunk=8, decode_segment=4)
+    mixed = eng.lane_closures(True)["mixed"]
+    lanes = 4
+    zeros = jnp.zeros((lanes,), jnp.int32)
+    for admitting in ([1], [0, 2, 3]):
+        nv = np.zeros((2, lanes), np.int32)
+        nv[0, admitting] = 8
+        fin = nv > 0
+        out = mixed(eng.fresh_state(lanes), zeros,
+                    jnp.zeros((lanes, 2), jnp.uint32),
+                    jnp.zeros((lanes,), bool), zeros, zeros + 4, zeros - 1,
+                    jnp.ones((2, lanes, 8), jnp.int32), jnp.asarray(nv),
+                    jnp.asarray(fin), jnp.zeros((lanes, 2), jnp.uint32))
+        # a lane finishing its prompt at step 0 decodes at step 1
+        np.testing.assert_array_equal(np.asarray(out[0]["t"]),
+                                      nv.sum(axis=0) + fin[0])
+    assert mixed._cache_size() == 1

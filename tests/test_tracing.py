@@ -4,7 +4,10 @@ dispatches ran, the named scopes in a compiled serving program, and
 the serve.* host spans in a profiler trace of one step."""
 import dataclasses
 import glob
+import json
 import os
+import subprocess
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -18,16 +21,21 @@ from repro.serve import Request, Scheduler, build_engine
 C, SEG, LANES = 8, 4, 2
 
 
-@pytest.fixture(scope="module")
-def engine():
+def _engine(mesh=None):
     cfg = dataclasses.replace(
         get_smoke_config("trimkv-paper-4b"), num_layers=2, d_model=64,
         d_ff=128, num_heads=4, num_kv_heads=2, vocab_size=64,
         gate_bias_init=3.0)
     params = T.init_params(jax.random.PRNGKey(0), cfg)
     gates = T.init_gate_params(jax.random.PRNGKey(1), cfg)
-    return build_engine(cfg, params, gates, budget=16, policy="trimkv",
-                        prefill_chunk=C, decode_segment=SEG)
+    return build_engine(cfg, params, gates, mesh=mesh, budget=16,
+                        policy="trimkv", prefill_chunk=C,
+                        decode_segment=SEG)
+
+
+@pytest.fixture(scope="module")
+def engine():
+    return _engine()
 
 
 def _requests(lens, max_new):
@@ -49,12 +57,34 @@ def _spy(sched):
         return segment(*args)
 
     def mix(*args):
-        seen["grids"].append((np.asarray(args[7]).shape,
-                              int(np.asarray(args[8]).sum())))
+        nv = np.asarray(args[8])
+        seen["grids"].append((list(np.asarray(args[7]).shape),
+                              int(nv.sum()), int((nv > 0).sum())))
         return mixed(*args)
 
     sched._segment, sched._mixed_nomem = seg, mix
     return seen
+
+
+def _serve_counted(engine, lens, max_new):
+    """Serve the requests interleaved; the counters and the spy's
+    record of the segment dispatches."""
+    sched = Scheduler(engine, n_lanes=LANES, interleaved=True)
+    seen = _spy(sched)
+    sched.run(_requests(lens, max_new))
+    return sched.stats(), seen
+
+
+def _check_counters(st, seen, lens, rows_of_grid):
+    assert seen["buckets"] and seen["grids"]
+    assert st["n_decode_steps"] == sum(seen["buckets"])
+    assert st["n_mixed_steps"] == sum(s[0] for s, _, _ in seen["grids"])
+    assert st["n_chunk_rows"] == sum(rows_of_grid(*g)
+                                     for g in seen["grids"])
+    assert all(s[1:] == [LANES, C] for s, _, _ in seen["grids"])
+    # every prompt row admitted ran once, padding excluded
+    assert st["n_chunk_rows_valid"] == sum(lens) == sum(
+        v for _, v, _ in seen["grids"])
 
 
 @pytest.mark.parametrize("lens,max_new", [
@@ -63,19 +93,47 @@ def _spy(sched):
     ([8, 16], [6, 2]),                        # chunk-aligned prompts
 ])
 def test_work_counters_match_the_dispatches(engine, lens, max_new):
-    sched = Scheduler(engine, n_lanes=LANES, interleaved=True)
-    seen = _spy(sched)
-    sched.run(_requests(lens, max_new))
-    st = sched.stats()
-    assert seen["buckets"] and seen["grids"]
-    assert st["n_decode_steps"] == sum(seen["buckets"])
-    assert st["n_mixed_steps"] == sum(s[0] for s, _ in seen["grids"])
-    assert st["n_chunk_rows"] == sum(s[0] * LANES * C
-                                     for s, _ in seen["grids"])
-    assert all(s[1:] == (LANES, C) for s, _ in seen["grids"])
-    # every prompt row admitted ran once, padding excluded
-    assert st["n_chunk_rows_valid"] == sum(lens) == sum(
-        v for _, v in seen["grids"])
+    """Unsharded lanes: the chunk sub-step runs only the (step, lane)
+    pairs that hold a chunk, C rows each."""
+    st, seen = _serve_counted(engine, lens, max_new)
+    _check_counters(st, seen, lens,
+                    lambda shape, valid, lane_steps: lane_steps * C)
+
+
+_ON_MESH = """
+import json, sys
+import jax
+jax.config.update("jax_platforms", "cpu")
+jax.config.update("jax_num_cpu_devices", 2)
+sys.path.insert(0, sys.argv[1])
+import test_tracing as tt
+from repro.launch.mesh import make_serving_mesh
+st, seen = tt._serve_counted(tt._engine(make_serving_mesh(2, 1)),
+                             *json.loads(sys.argv[2]))
+print(json.dumps([st, seen]))
+"""
+
+
+def test_work_counters_on_a_lane_sharded_mesh():
+    """Lanes sharded over "data" (a 2x1 mesh of virtual CPU devices, in
+    a subprocess, as tests/test_shard_serve.py runs them): the mixed
+    program keeps the lane-aligned chunk sub-step, so every mixed step
+    counts all LANES x C rows."""
+    lens, max_new = [5, 11, 19, 8, 14], [4, 8, 4, 8, 4]
+    env = dict(os.environ, PYTHONPATH=os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "src"))
+    env.pop("XLA_FLAGS", None)
+    p = subprocess.run(
+        [sys.executable, "-c", _ON_MESH,
+         os.path.dirname(os.path.abspath(__file__)),
+         json.dumps([lens, max_new])],
+        capture_output=True, text=True, timeout=600, env=env)
+    assert p.returncode == 0, p.stdout[-3000:] + p.stderr[-3000:]
+    st, seen = json.loads(p.stdout.strip().splitlines()[-1])
+    _check_counters(st, seen, lens,
+                    lambda shape, valid, lane_steps: shape[0] * LANES * C)
+    assert st["n_chunk_rows"] > C * sum(g[2] for g in seen["grids"])
 
 
 def test_serving_program_carries_the_scope_names(engine):
