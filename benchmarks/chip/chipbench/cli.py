@@ -57,10 +57,11 @@ def _compile_cache(root: str) -> str:
     return path
 
 
-def build_program(model: dict, serve: dict, w: dict):
+def build_program(model: dict, serve: dict, w: dict, ref):
     """The system under test: build_engine over the benchmark's weights
-    and an n-lane Scheduler, with the serving settings `serve` (a
-    Cell's `serve`). Returns (scheduler, Request class)."""
+    `w`, re-nested by the configuration's reference `ref`, and an
+    n-lane Scheduler, with the serving settings `serve` (a Cell's
+    `serve`). Returns (scheduler, Request class)."""
     import jax
     from repro.configs import ModelConfig
     from repro.models import transformer as T
@@ -69,7 +70,7 @@ def build_program(model: dict, serve: dict, w: dict):
 
     cfg = ModelConfig(**{**model, "attn_pattern":
                          tuple(model["attn_pattern"])})
-    params, gates = weights.to_program(w)
+    params, gates = ref.to_program(w, model)
     key = jax.random.PRNGKey(0)
     weights.check_layout(
         params, gates, jax.eval_shape(lambda: T.init_params(key, cfg)),
@@ -94,6 +95,26 @@ def _lateness(recs, window):
             "max_s": float(max(late)), "n": len(late)}
 
 
+def set_up(cell, seed: int, seconds: float, trace: bool):
+    """Weights from the seed in the reference's layout, the program
+    over them, the traffic's generator, and a client that has served
+    every shape the window can meet and the generator's warm requests.
+    Returns (reference, weights, scheduler, generator, client)."""
+    from chipbench import client, loops, weights
+
+    model, serve = cell.config["model"], cell.serve
+    ref = cell.reference()
+    w = weights.make_weights(ref.canonical_shapes(model), seed)
+    sched, request_cls = build_program(model, serve, w, ref)
+    loop = loops.make(cell.traffic, seed=seed, seconds=seconds,
+                      vocab=model["vocab_size"])
+    drv = client.Client(sched, request_cls, spans=trace)
+    drv.warm_shapes(loop.max_prompt, serve["prefill_chunk"],
+                    model["vocab_size"], first_rid=WARM_RID)
+    drv.warm_start(loop.warm())
+    return ref, w, sched, loop, drv
+
+
 def run_cell(root: str, bench: str, workload: str, seed: int,
              seconds: float, trace: bool, *, t_start: float,
              require_tpu: bool = True, out=sys.stdout, err=sys.stderr,
@@ -102,9 +123,8 @@ def run_cell(root: str, bench: str, workload: str, seed: int,
     `after(cell, run, w, seed, picked)`, when given, runs once the check
     is done (prove.py reads the lower-precision control there); the
     benchmark's own runs give none."""
-    from chipbench import client, correct, loops, peaks, spec, work
+    from chipbench import client, correct, peaks, spec, work
     from chipbench import trace as tracing
-    from chipbench import weights
 
     cell = spec.load(root, bench, workload)
     import repro.serve  # noqa: F401  (fail at once where the program is absent)
@@ -115,16 +135,7 @@ def run_cell(root: str, bench: str, workload: str, seed: int,
                              else "TPU v5 lite")
     _compile_cache(root)
     clock = client.CompileClock()
-    model, serve = cell.config["model"], cell.serve
-
-    w = weights.make_weights(model, seed)
-    sched, request_cls = build_program(model, serve, w)
-    loop = loops.make(cell.traffic, seed=seed, seconds=seconds,
-                      vocab=model["vocab_size"])
-    drv = client.Client(sched, request_cls, spans=trace)
-    drv.warm_shapes(loop.max_prompt, serve["prefill_chunk"],
-                    model["vocab_size"], first_rid=WARM_RID)
-    drv.warm_start(loop.warm())
+    ref, w, sched, loop, drv = set_up(cell, seed, seconds, trace)
     stats0 = sched.stats()
     comp0 = clock.snapshot()
 
@@ -146,10 +157,13 @@ def run_cell(root: str, bench: str, workload: str, seed: int,
 
     recs = list(drv.recs.values())
     recs = [r for r in recs if r.req.rid < WARM_RID]
-    run = work.Run(cell=cell.workload, model=model, serve=serve,
-                   traffic=cell.traffic, window=drv.window, recs=recs,
-                   steps=drv.steps, peak_bytes=peak_bytes, setup_s=setup_s,
-                   peak=chip_peaks)
+    stats = {k: v - stats0.get(k, 0) for k, v in stats1.items()
+             if isinstance(v, (int, float))}
+    run = work.Run(cell=cell.workload, model=cell.config["model"],
+                   serve=cell.serve, traffic=cell.traffic,
+                   window=drv.window, recs=recs, steps=drv.steps,
+                   peak_bytes=peak_bytes, setup_s=setup_s, peak=chip_peaks,
+                   stats=stats, reference=ref)
     del drv, sched
     gc.collect()
 
@@ -157,12 +171,10 @@ def run_cell(root: str, bench: str, workload: str, seed: int,
         run.trace = tracing.load(trace_dir)
         shutil.rmtree(trace_dir, ignore_errors=True)
 
-    in_window = [k for k in stats1 if isinstance(stats1[k], (int, float))]
     side = {
         "generator_lateness": _lateness(recs, run.window),
         "compiles_in_window": {k: comp1[k] - comp0[k] for k in comp1},
-        "scheduler_stats_in_window": {k: stats1[k] - stats0.get(k, 0)
-                                      for k in in_window},
+        "scheduler_stats_in_window": stats,
         "window_s": run.seconds,
     }
     print("run: " + json.dumps(side), file=out, flush=True)

@@ -9,7 +9,8 @@ lane, kv head and layer, the queries read and the output written once;
 nothing that a faster kernel could skip (re-reads of a slab per query
 head, probabilities nobody consumes) is counted.
 
-`m` is the `model` block of a configuration file.
+`m` is the `model` block of a configuration file; `ref`, where given,
+is its reference module, which may count its own layer's weights.
 """
 from __future__ import annotations
 
@@ -17,9 +18,13 @@ BF16 = 2
 F32 = 4
 
 
-def layer_weights(m: dict) -> int:
-    """Weights a token multiplies per layer: Q/K/V/O, the SwiGLU MLP
-    and the retention gate."""
+def layer_weights(m: dict, ref=None) -> int:
+    """Weights a token multiplies per layer: the reference's own count
+    where it defines `layer_weights(m)` (a sparse FFN counts only the
+    experts a token runs), else Q/K/V/O, the dense SwiGLU MLP and the
+    retention gate."""
+    if hasattr(ref, "layer_weights"):
+        return ref.layer_weights(m)
     d, F, G = m["d_model"], m["d_ff"], m["gate_hidden"]
     q = m["num_heads"] * m["head_dim"]
     kv = m["num_kv_heads"] * m["head_dim"]
@@ -36,20 +41,21 @@ def attn_flops(m: dict, keys: float) -> float:
     return 4.0 * m["num_heads"] * m["head_dim"] * keys
 
 
-def decode_token_flops(m: dict, keys: float) -> float:
+def decode_token_flops(m: dict, keys: float, ref=None) -> float:
     """One decoded token through every layer and the head."""
     L = m["num_layers"]
-    return L * (2.0 * layer_weights(m) + attn_flops(m, keys)) + \
+    return L * (2.0 * layer_weights(m, ref) + attn_flops(m, keys)) + \
         head_flops(m)
 
 
-def prefill_chunk_flops(m: dict, kept: int, n: int) -> float:
+def prefill_chunk_flops(m: dict, kept: int, n: int, ref=None) -> float:
     """n prompt tokens of one chunk through every layer, attending to
     `kept` retained keys and causally to the chunk (the head runs for
     the prompt's last token only; see prefill_head)."""
     L = m["num_layers"]
     pairs = n * kept + n * (n + 1) / 2.0
-    return L * (2.0 * layer_weights(m) * n + attn_flops(m, 1.0) * pairs)
+    return L * (2.0 * layer_weights(m, ref) * n
+                + attn_flops(m, 1.0) * pairs)
 
 
 def decode_attn(m: dict, keys: int):

@@ -8,7 +8,8 @@ files.
   <bench>/cells/<workload>.json         budget, lanes, limits
   <bench>/traffic/<traffic>.json        loop kind, laws, rate
   <bench>/metrics/<metric>.py           read(run) -> number or None
-  <bench>/references/<reference>.py     the configuration's reference
+  <bench>/references/<reference>.py     the configuration's weight layout
+                                        and plain reference
 """
 from __future__ import annotations
 

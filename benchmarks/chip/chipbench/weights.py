@@ -1,18 +1,25 @@
 """Random weights made on the device from the run's seed.
 
-`make_weights` builds every tensor in ONE jitted call, in the type it
-is served in: matrices in bf16, norm scales and the retention gates in
-float32. The layout is the benchmark's own (`canonical`): per-layer
-tensors stacked on a leading layer axis under plain names. The plain
-reference reads this layout; `to_program` re-nests the same arrays
-(no copy) into the tree the program under test takes.
+The layout belongs to the configuration's reference
+(`references/<reference>.py`): its `canonical_shapes(m)` names every
+tensor as `{name: (shape, dtype, role)}`, the plain reference reads
+that layout, and its `to_program(w, m)` re-nests the same arrays (no
+copy) into the tree the program under test takes. Nothing here knows
+a model: `make_weights` draws whatever tensors it is given, in ONE
+jitted call, by role, and `check_layout` holds a re-nested tree
+against the program's own init.
 
-Scales: projections N(0, 1/fan_in); embedding N(0, 1); norm scales
-1 + N(0, 0.1); QKV biases N(0, 0.1). The retention gate's output layer
-and bias are drawn so that a gate logit is about N(4.5, 1.4), which
-spreads beta = sigmoid(logit) over roughly (0.95, 0.9995) as a trained
-gate does; at the program's training init (bias 18) beta is 1.0 in
-float32 and every keep score ties.
+Roles and their draws:
+  matrix     N(0, 1/fan_in), fan_in the second-to-last axis
+  embed      N(0, 1)
+  norm       1 + N(0, 0.1)
+  bias       N(0, 0.1)
+  gate_out   N(0, 0.1): the retention gate's output layer
+  gate_bias  uniform(3.5, 5.5): the retention gate's bias
+The gate's output layer and bias make a gate logit about N(4.5, 1.4),
+which spreads beta = sigmoid(logit) over roughly (0.95, 0.9995) as a
+trained gate does; at the program's training init (bias 18) beta is
+1.0 in float32 and every keep score ties.
 """
 from __future__ import annotations
 
@@ -37,48 +44,23 @@ def padded_vocab(vocab: int) -> int:
     return (vocab + 255) // 256 * 256
 
 
-def canonical_shapes(m: dict) -> dict:
-    """name -> (shape, dtype) of every canonical tensor of model `m`
-    (the `model` block of a configuration file)."""
-    L, d, F = m["num_layers"], m["d_model"], m["d_ff"]
-    q, kv = m["num_heads"] * m["head_dim"], m["num_kv_heads"] * m["head_dim"]
-    G, Vp = m["gate_hidden"], padded_vocab(m["vocab_size"])
-    bf = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}[m["dtype"]]
-    f32 = jnp.float32
-    shapes = {
-        "embed": ((Vp, d), bf),
-        "unembed": ((d, Vp), bf),
-        "final_norm": ((d,), f32),
-        "attn_norm": ((L, d), f32),
-        "wq": ((L, d, q), bf),
-        "wk": ((L, d, kv), bf),
-        "wv": ((L, d, kv), bf),
-        "wo": ((L, q, d), bf),
-        "mlp_norm": ((L, d), f32),
-        "w_gate": ((L, d, F), bf),
-        "w_up": ((L, d, F), bf),
-        "w_down": ((L, F, d), bf),
-        "gate_w1": ((L, d, G), f32),
-        "gate_w2": ((L, G, m["num_kv_heads"]), f32),
-        "gate_b": ((L, m["num_kv_heads"]), f32),
-    }
-    if m["qkv_bias"]:
-        shapes.update(bq=((L, q), bf), bk=((L, kv), bf), bv=((L, kv), bf))
-    return shapes
-
-
-def _draw(name, key, shape, dtype):
-    if name.endswith("norm"):
+def _draw(role, key, shape, dtype):
+    if role == "norm":
         return (1.0 + NORM_SCALE_STD * jax.random.normal(key, shape)
                 ).astype(dtype)
-    if name == "gate_b":
+    if role == "gate_bias":
         lo, hi = GATE_BIAS_RANGE
         return jax.random.uniform(key, shape, jnp.float32, lo, hi)
-    if name in ("bq", "bk", "bv"):
+    if role == "bias":
         return (BIAS_STD * jax.random.normal(key, shape)).astype(dtype)
-    if name == "embed":
+    if role == "embed":
         return jax.random.normal(key, shape).astype(dtype)
-    scale = GATE_W2_SCALE if name == "gate_w2" else 1.0 / np.sqrt(shape[-2])
+    if role == "gate_out":
+        scale = GATE_W2_SCALE
+    elif role == "matrix":
+        scale = 1.0 / np.sqrt(shape[-2])
+    else:
+        raise ValueError(f"unknown weight role {role!r}")
     return (scale * jax.random.normal(key, shape)).astype(dtype)
 
 
@@ -87,44 +69,18 @@ def _maker(items):
     @jax.jit
     def make(key):
         keys = jax.random.split(key, len(items))
-        return {n: _draw(n, k, s, dt)
-                for k, (n, s, dt) in zip(keys, items)}
+        return {n: _draw(r, k, s, dt)
+                for k, (n, s, dt, r) in zip(keys, items)}
     return make
 
 
-def make_weights(m: dict, seed: int) -> dict:
-    """All canonical tensors of model `m`, on the default device, drawn
-    from `seed` in one jitted call."""
-    items = tuple((n, s, dt) for n, (s, dt) in
-                  sorted(canonical_shapes(m).items()))
+def make_weights(shapes: dict, seed: int) -> dict:
+    """Every tensor of `shapes` ({name: (shape, dtype, role)}, a
+    reference's `canonical_shapes`), on the default device, drawn from
+    `seed` in one jitted call: names in sorted order, one key each."""
+    items = tuple((n, tuple(s), dt, r) for n, (s, dt, r) in
+                  sorted(shapes.items()))
     return _maker(items)(jax.random.PRNGKey(seeds.int31(seed, "weights")))
-
-
-def to_program(w: dict):
-    """(params, gates) in the tree the program's dense 'global' model
-    takes: one scanned unit of one block, tensors stacked per layer.
-    The arrays are the same objects as in `w`."""
-    def dense(name, bias=None):
-        out = {"w": w[name]}
-        if bias is not None and bias in w:
-            out["b"] = w[bias]
-        return out
-
-    block = {
-        "norm1": {"scale": w["attn_norm"]},
-        "attn": {"wq": dense("wq", "bq"), "wk": dense("wk", "bk"),
-                 "wv": dense("wv", "bv"), "wo": dense("wo")},
-        "norm2": {"scale": w["mlp_norm"]},
-        "ffn": {"gate": dense("w_gate"), "up": dense("w_up"),
-                "down": dense("w_down")},
-    }
-    params = {"embed": w["embed"], "final_norm": {"scale": w["final_norm"]},
-              "unembed": {"w": w["unembed"]}, "layers": (block,),
-              "tail": ()}
-    gates = {"layers": ({"w1": {"w": w["gate_w1"]},
-                         "w2": {"w": w["gate_w2"]}, "b": w["gate_b"]},),
-             "tail": ()}
-    return params, gates
 
 
 def check_layout(params, gates, expected_params, expected_gates):

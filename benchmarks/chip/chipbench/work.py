@@ -23,6 +23,9 @@ class Run:
     setup_s: float
     peak: dict                  # the chip's published peaks
     trace: Optional[object] = None   # trace.Trace of a --trace 1 run
+    # the window's deltas of the scheduler's counters (Scheduler.stats())
+    stats: dict = dataclasses.field(default_factory=dict)
+    reference: Optional[object] = None   # the configuration's reference
 
     @property
     def seconds(self) -> float:
@@ -97,10 +100,12 @@ def prefill_chunks(run: Run):
 
 def model_flops(run: Run) -> float:
     """Model FLOPs of all the tokens processed in the window."""
-    m = run.model
-    total = sum(flops.decode_token_flops(m, k + 1) for k in decode_keys(run))
+    m, ref = run.model, run.reference
+    total = sum(flops.decode_token_flops(m, k + 1, ref)
+                for k in decode_keys(run))
     chunks = prefill_chunks(run)
-    total += sum(flops.prefill_chunk_flops(m, kept, n) for kept, n in chunks)
+    total += sum(flops.prefill_chunk_flops(m, kept, n, ref)
+                 for kept, n in chunks)
     n_prompts = sum(1 for kept, _ in chunks if kept == 0)
     return total + n_prompts * flops.head_flops(m)
 

@@ -27,6 +27,11 @@ blocks of rows so that it fits beside the served weights. With
 mode="fp8" every matrix product takes its inputs rounded to float8
 e4m3 with a per-tensor (weights) or per-row (activations) scale: the
 lower-precision control that the comparison has to reject.
+
+The layout of the weights is this module's too (`canonical_shapes`,
+`to_program`): per-layer tensors stacked on a leading layer axis under
+plain names, which the reference reads and the harness re-nests into
+the program's tree.
 """
 from __future__ import annotations
 
@@ -36,6 +41,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from chipbench.weights import padded_vocab
+
 HI = jax.lax.Precision.HIGHEST
 ROWS = 2048          # rows per block of the projections and the MLP
 Q_BLOCK = 256        # query rows per block of the attention
@@ -44,6 +51,66 @@ N_BUCKET = 512       # padded compared-position counts likewise
 GROUP = 4            # sequences per batch of the reference
 FP8 = jnp.float8_e4m3fn
 FP8_MAX = 448.0
+
+
+def canonical_shapes(m: dict) -> dict:
+    """name -> (shape, dtype, role) of every tensor of model `m` (the
+    `model` block of a configuration file); roles as
+    `chipbench.weights` draws them."""
+    L, d, F = m["num_layers"], m["d_model"], m["d_ff"]
+    q, kv = m["num_heads"] * m["head_dim"], m["num_kv_heads"] * m["head_dim"]
+    G, Vp = m["gate_hidden"], padded_vocab(m["vocab_size"])
+    bf = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}[m["dtype"]]
+    f32 = jnp.float32
+    shapes = {
+        "embed": ((Vp, d), bf, "embed"),
+        "unembed": ((d, Vp), bf, "matrix"),
+        "final_norm": ((d,), f32, "norm"),
+        "attn_norm": ((L, d), f32, "norm"),
+        "wq": ((L, d, q), bf, "matrix"),
+        "wk": ((L, d, kv), bf, "matrix"),
+        "wv": ((L, d, kv), bf, "matrix"),
+        "wo": ((L, q, d), bf, "matrix"),
+        "mlp_norm": ((L, d), f32, "norm"),
+        "w_gate": ((L, d, F), bf, "matrix"),
+        "w_up": ((L, d, F), bf, "matrix"),
+        "w_down": ((L, F, d), bf, "matrix"),
+        "gate_w1": ((L, d, G), f32, "matrix"),
+        "gate_w2": ((L, G, m["num_kv_heads"]), f32, "gate_out"),
+        "gate_b": ((L, m["num_kv_heads"]), f32, "gate_bias"),
+    }
+    if m["qkv_bias"]:
+        shapes.update(bq=((L, q), bf, "bias"), bk=((L, kv), bf, "bias"),
+                      bv=((L, kv), bf, "bias"))
+    return shapes
+
+
+def to_program(w: dict, m: dict):
+    """(params, gates) in the tree the program's dense 'global' model
+    takes: one scanned unit of one block, tensors stacked per layer.
+    The arrays are the same objects as in `w`; `m` is not read, since
+    the dense layout is the same at every size."""
+    def dense(name, bias=None):
+        out = {"w": w[name]}
+        if bias is not None and bias in w:
+            out["b"] = w[bias]
+        return out
+
+    block = {
+        "norm1": {"scale": w["attn_norm"]},
+        "attn": {"wq": dense("wq", "bq"), "wk": dense("wk", "bk"),
+                 "wv": dense("wv", "bv"), "wo": dense("wo")},
+        "norm2": {"scale": w["mlp_norm"]},
+        "ffn": {"gate": dense("w_gate"), "up": dense("w_up"),
+                "down": dense("w_down")},
+    }
+    params = {"embed": w["embed"], "final_norm": {"scale": w["final_norm"]},
+              "unembed": {"w": w["unembed"]}, "layers": (block,),
+              "tail": ()}
+    gates = {"layers": ({"w1": {"w": w["gate_w1"]},
+                         "w2": {"w": w["gate_w2"]}, "b": w["gate_b"]},),
+             "tail": ()}
+    return params, gates
 
 
 def _q8(x, axis):

@@ -30,24 +30,33 @@ def _dec(i, start, named_=True, dur=40):
             f'custom_call_target="tpu_custom_call"', start, dur)
 
 
-def _chunk(i, start, named_=True):
+def _chunk(i, start, named_=True, dur=100):
     name = "chunk_attn" if named_ else "closed_call"
     return (f"%{name}.{i} = bf16[8,8,16]{{2,1,0}} custom-call(bf16[8,8,16]"
             f'{{2,1,0}} %a), custom_call_target="tpu_custom_call"', start,
-            100)
+            dur)
 
 
 def _fusion(i, start, dur):
     return (f"%fusion.{i} = f32[8] fusion(f32[8] %p)", start, dur)
 
 
-def hand_trace(named_=True):
+def _chunks(i, start, named_, lanes):
+    """The chunk kernel of one layer of the mixed step: one event per
+    admitting lane, 100 ns in all."""
+    dur = 100 // lanes
+    return [_chunk(i + 10 * n, start + n * dur, named_, dur)
+            for n in range(lanes)]
+
+
+def hand_trace(named_=True, lanes=1):
     """Window 0..1000 ns, 2 layers. A decode segment of 2 steps in the
     step 0..300, and in the step 400..800 an admission segment: one
-    mixed step, then one decode step (its decode kernels 30 ns, no
-    fusion). The other steps run the decode kernel (40 ns) and a fusion
-    (5 ns) per layer, the mixed one also the chunk kernel (100 ns) and
-    a second fusion (10 ns); a loop (110..205) holds the second decode
+    mixed step in which `lanes` lanes admit, then one decode step (its
+    decode kernels 30 ns, no fusion). The other steps run the decode
+    kernel (40 ns) and a fusion (5 ns) per layer, the mixed one also
+    the chunk kernel (100 ns, split between the admitting lanes) and a
+    second fusion (10 ns); a loop (110..205) holds the second decode
     step."""
     ops = [_dec(1, 10, named_), _fusion(1, 50, 5),
            _dec(2, 60, named_), _fusion(2, 100, 5),
@@ -55,9 +64,9 @@ def hand_trace(named_=True):
            _dec(3, 110, named_), _fusion(3, 150, 5),
            _dec(4, 160, named_), _fusion(4, 200, 5),
            _dec(5, 410, named_), _fusion(5, 450, 5),
-           _chunk(6, 460, named_), _fusion(6, 560, 10),
+           *_chunks(6, 460, named_, lanes), _fusion(6, 560, 10),
            _dec(7, 570, named_), _fusion(7, 610, 5),
-           _chunk(8, 615, named_), _fusion(8, 715, 10),
+           *_chunks(8, 615, named_, lanes), _fusion(8, 715, 10),
            _dec(9, 730, named_, dur=30), _dec(10, 760, named_, dur=30)]
     host = [("bench.window", 0, 1000), ("sched.step", 0, 300),
             ("sched.step", 400, 400), ("client.harvest", 300, 50)]
@@ -74,15 +83,23 @@ def _rec(rid, prompt_len, first, seen, in_window):
                0.0, first=first, seen=seen, in_window=in_window)
 
 
-def hand_run(trace):
+PROMPTS = (5, 3)      # prompt lengths of the lanes admitting in the window
+
+
+def hand_run(trace, lanes=1, counters=True):
     # a request decoding through the window (prompt 3, tokens 3 and 4
-    # harvested in it, made from positions 4 and 5) and one admitted in
-    # it (prompt 5, one chunk, its first token only)
-    recs = [_rec(0, 3, -5.0, 4, 2), _rec(1, 5, 2.0, 1, 1)]
+    # harvested in it, made from positions 4 and 5) and `lanes` admitted
+    # in it in one mixed step (prompts of one chunk, first token only),
+    # with the scheduler's counters of that step: C rows per admitting
+    # lane
+    recs = [_rec(0, 3, -5.0, 4, 2)]
+    recs += [_rec(1 + n, PROMPTS[n], 2.0, 1, 1) for n in range(lanes)]
+    stats = {"n_mixed_steps": 1, "n_chunk_rows": lanes * 8,
+             "n_chunk_rows_valid": sum(PROMPTS[:lanes])} if counters else {}
     return work.Run(cell={}, model=MODEL, serve=SERVE, traffic={},
                     window=(0.0, 10.0), recs=recs, steps=[], peak_bytes=0,
                     setup_s=0.0, peak=peaks.peaks("TPU v5 lite"),
-                    trace=trace)
+                    trace=trace, stats=stats)
 
 
 def metric(name):
@@ -108,8 +125,9 @@ def test_kernel_events_and_step_split():
         "mixed": [pytest.approx(400e-9), 4, 2]}
 
 
-def test_readers_against_hand_counts():
-    run = hand_run(hand_trace())
+@pytest.mark.parametrize("lanes", [1, 2])
+def test_readers_against_hand_counts(lanes):
+    run = hand_run(hand_trace(lanes=lanes), lanes)
     p = run.peak
     # two layers of decode attention for keys 4 and 5, in 6 x 40 ns and
     # 2 x 30 ns
@@ -117,15 +135,18 @@ def test_readers_against_hand_counts():
                     for k in (4, 5))
     assert metric("decode_attn_roofline").read(run) == pytest.approx(
         100 * ideal / 300e-9)
-    # two layers of one 5-row chunk over no kept keys, in 2 x 100 ns
+    # two layers of a 5-row (and a 3-row) chunk over no kept keys, in
+    # 2 x 100 ns
     chunk = metric("chunk_attn_roofline")
-    ideal = 2 * flops.roofline_seconds(*chunk.needed(MODEL, 0, 5), p)
+    ideal = 2 * sum(flops.roofline_seconds(*chunk.needed(MODEL, 0, n), p)
+                    for n in PROMPTS[:lanes])
     assert chunk.read(run) == pytest.approx(100 * ideal / 200e-9)
-    # 5 valid rows of 1 mixed step x 4 lanes x 8 rows
+    # 5 (and 3) valid rows of 8 per admitting lane, from the counters
     assert metric("sched.chunk_row_fill").read(run) == pytest.approx(
-        100 * 5 / 32)
+        100 * sum(PROMPTS[:lanes]) / (8 * lanes))
     # 300 ns for 2 decode steps; the admission segment's 400 ns less its
-    # one decode step at 150 ns, for 1 mixed step
+    # one decode step at 150 ns, for the 1 mixed step the counters hold
+    # however many lanes' chunk kernels it ran
     assert metric("model.decode_step_ms").read(run) == pytest.approx(
         150e-6)
     assert metric("model.mixed_step_ms").read(run) == pytest.approx(
@@ -144,10 +165,12 @@ def test_chunk_attn_needed_hand_count():
 
 @pytest.mark.parametrize("name", NEW)
 def test_readers_find_nothing_without_the_names(name):
-    """A program without the names (the trace of a parent commit), and
-    an untraced run, give no reading rather than a wrong one."""
-    assert metric(name).read(hand_run(hand_trace(named_=False))) is None
-    assert metric(name).read(hand_run(None)) is None
+    """A program without the names (the trace of a parent commit) or
+    the counters, and an untraced run, give no reading rather than a
+    wrong one."""
+    bare = hand_trace(named_=False)
+    assert metric(name).read(hand_run(bare, counters=False)) is None
+    assert metric(name).read(hand_run(None, counters=False)) is None
 
 
 def test_older_reductions_are_unmoved_by_names_and_spans():
